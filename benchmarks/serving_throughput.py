@@ -92,8 +92,8 @@ def run(smoke: bool = False) -> bool:
     # interpret-mode paged kernel vs the engine's gather fallback
     from repro import tcec_paged_attention
     rng = np.random.default_rng(2)
-    kp = jnp.asarray(rng.standard_normal((9, 8, 2, 64)), jnp.bfloat16)
-    vp = jnp.asarray(rng.standard_normal((9, 8, 2, 64)), jnp.bfloat16)
+    kp = jnp.asarray(rng.standard_normal((9, 2, 8, 64)), jnp.bfloat16)
+    vp = jnp.asarray(rng.standard_normal((9, 2, 8, 64)), jnp.bfloat16)
     q = jnp.asarray(rng.standard_normal((2, 8, 64)), jnp.float32)
     bt = jnp.asarray(np.arange(1, 9).reshape(2, 4), jnp.int32)
     lens = jnp.asarray([13, 27], jnp.int32)
@@ -102,8 +102,8 @@ def run(smoke: bool = False) -> bool:
     from repro.models.layers import _decode_attend
     class _C:
         attn_softcap = None
-    kg = kp[bt].reshape(2, 32, 2, 64)
-    vg = vp[bt].reshape(2, 32, 2, 64)
+    kg = jnp.swapaxes(kp[bt], 2, 3).reshape(2, 32, 2, 64)   # head-major
+    vg = jnp.swapaxes(vp[bt], 2, 3).reshape(2, 32, 2, 64)   # pools
     fb = _decode_attend(q[:, None], kg, vg, _C(), lens - 1, 0)[:, 0]
     kerr = float(jnp.max(jnp.abs(kout - fb)))
     kernel_ok = kerr < 5e-2

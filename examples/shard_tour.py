@@ -22,10 +22,11 @@ import numpy as np                                               # noqa: E402
 
 import repro                                                     # noqa: E402
 from repro import numerics                                       # noqa: E402
+from repro.launch.mesh import make_mesh                          # noqa: E402
 from repro.parallel import ctx                                   # noqa: E402
 
 # ----------------------------------------------------- 1. mesh + plans
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 print(f"mesh: {dict(mesh.shape)} over {len(jax.devices())} devices")
 
 plan = repro.shmap.matmul_plan((256, 256), (256, 256), mesh)

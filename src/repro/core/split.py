@@ -70,7 +70,11 @@ def split(x: jax.Array, dtype, n_splits: int, scale_bits: int,
         a = cast(r)
         out.append(a)
         if i + 1 < n_splits:
-            r = (r - a.astype(jnp.float32)) * scale
+            # the barrier keeps XLA from folding the narrowing round trip
+            # away (TPU allows excess precision), which would zero every
+            # correction term
+            r = (r - jax.lax.optimization_barrier(a).astype(jnp.float32)) \
+                * scale
     return out
 
 

@@ -24,9 +24,10 @@ Dispatch rules (see docs/kernels.md):
   1. the policy is a bf16 split policy (``tcec_bf16x3`` / ``tcec_bf16x6``):
      plain policies are a single XLA dot, and the fp16 reproduction
      policies model CUDA Tensor Cores, which the bf16 MXU kernel cannot;
-  2. the contraction is 2-D or single-batch-dim 3-D with one m/n/k dim each
-     (after ``pdot``'s canonical transpose this covers every model-zoo
-     GEMM; multi-dim m/n einsums stay on XLA);
+  2. the contraction has at most one (leading) batch dim; its free and
+     contracted dims collapse onto the kernel's ``(B?, M, K) @ (B?, K, N)``
+     (a projection ``bsd,dhk->bshk`` runs as one ``(b*s, d) @ (d, h*k)``
+     GEMM);
   3. M, N, K all reach ``min_dim`` (tiny GEMMs lose more to 128-padding
      than the fusion wins);
   4. the backend is TPU — or ``force`` is set, which runs the kernel in
@@ -127,30 +128,34 @@ def eligible_policy(policy: PrecisionPolicy) -> bool:
 
 def _canonicalize(a, b, dims):
     """Map a ``dot_general`` spec onto the kernel's ``(B?, M, K) @ (B?, K, N)``
-    layout, or return None when the contraction doesn't fit (rule 2).
+    layout: ``(a, b, out_shape)``, or None when the contraction doesn't fit
+    (rule 2).
 
-    Handles the backward GEMMs too: ``custom_vjp`` calls ``_dot_impl`` with
-    the contraction on either operand side, so a transposed operand is
-    swapped into canonical order here (the kernel output order matches
-    ``dot_general``'s ``(batch, lhs-free, rhs-free)``).
+    Free dims of each operand collapse into M (resp. N) and the contracted
+    dims into K, after a transpose when they are not already in that order
+    (``custom_vjp``'s backward GEMMs contract leading dims).  ``out_shape``
+    is ``dot_general``'s ``(batch, lhs-free..., rhs-free...)``, which the
+    kernel's ``(B?, M, N)`` output reshapes to.
     """
+    import math
     (ca, cb), (ba, bb) = dims
-    if len(ca) != 1 or len(cb) != 1:
-        return None
     nb = len(ba)
     if nb > 1 or tuple(ba) != tuple(range(nb)) or tuple(bb) != tuple(range(nb)):
         return None
-    if a.ndim != nb + 2 or b.ndim != nb + 2:
+    a_free = [d for d in range(nb, a.ndim) if d not in ca]
+    b_free = [d for d in range(nb, b.ndim) if d not in cb]
+    if not ca or not a_free or not b_free:
         return None
-    if ca[0] == nb:            # contraction leads -> swap to (.., m, k)
-        a = jnp.swapaxes(a, nb, nb + 1)
-    elif ca[0] != nb + 1:
-        return None
-    if cb[0] == nb + 1:        # contraction trails -> swap to (.., k, n)
-        b = jnp.swapaxes(b, nb, nb + 1)
-    elif cb[0] != nb:
-        return None
-    return a, b
+    batch = list(range(nb))
+    a = jnp.transpose(a, batch + a_free + list(ca))
+    b = jnp.transpose(b, batch + list(cb) + b_free)
+    lead = tuple(a.shape[:nb])
+    m_shape = a.shape[nb:nb + len(a_free)]
+    n_shape = b.shape[nb + len(cb):]
+    K = math.prod(a.shape[nb + len(a_free):])
+    a = a.reshape(lead + (math.prod(m_shape), K))
+    b = b.reshape(lead + (K, math.prod(n_shape)))
+    return a, b, lead + tuple(m_shape) + tuple(n_shape)
 
 
 def _mesh_plan_or_decline(shapes_plan, cfg):
@@ -188,7 +193,7 @@ def _decide(a, b, policy: PrecisionPolicy, dims, cfg):
     canon = _canonicalize(a, b, dims)
     if canon is None:
         return None, "shape-unsupported"
-    at, bt = canon
+    at, bt, _ = canon
     M, K = at.shape[-2], at.shape[-1]
     N = bt.shape[-1]
     if min(M, N, K) < cfg.min_dim:
@@ -209,7 +214,7 @@ def decide(a, b, policy: PrecisionPolicy, dims, cfg=None):
     (Probing only — ``maybe_dispatch`` records the explain decision.)
     """
     canon, _ = _decide(a, b, policy, dims, _cfg(cfg))
-    return canon
+    return None if canon is None else canon[:2]
 
 
 def maybe_dispatch(a, b, policy: PrecisionPolicy, dims, cfg=None):
@@ -227,7 +232,7 @@ def maybe_dispatch(a, b, policy: PrecisionPolicy, dims, cfg=None):
         _explain("matmul", policy.name,
                  (tuple(a.shape), tuple(b.shape)), rule)
         return None
-    at, bt = canon
+    at, bt, out_shape = canon
     from . import shmap
     mesh, plan = _mesh_plan_or_decline(
         lambda m: shmap.matmul_plan(at.shape, bt.shape, m), cfg)
@@ -239,17 +244,17 @@ def maybe_dispatch(a, b, policy: PrecisionPolicy, dims, cfg=None):
         if plan == "decline":         # decide() screens this; stay graceful
             _explain("matmul", policy.name, ident[1:], "mesh-declined")
             return None
-        return _guarded(
-            "matmul", ident, cfg,
-            lambda: shmap.sharded_matmul(at, bt, policy=policy.name,
-                                         mesh=mesh, cfg=cfg, plan=plan),
-            "kernel.matmul")
 
-    def _run():
-        block = tuned_block(M, N, K, policy.name, batch=B, cfg=cfg)
-        return ops.tcec_matmul(at, bt, policy=policy.name, block=block,
-                               interpret=cfg.interpret, cfg=cfg)
-    return _guarded("matmul", ident, cfg, _run, "kernel.matmul")
+        def _run():
+            return shmap.sharded_matmul(at, bt, policy=policy.name,
+                                        mesh=mesh, cfg=cfg, plan=plan)
+    else:
+        def _run():
+            block = tuned_block(M, N, K, policy.name, batch=B, cfg=cfg)
+            return ops.tcec_matmul(at, bt, policy=policy.name, block=block,
+                                   interpret=cfg.interpret, cfg=cfg)
+    out = _guarded("matmul", ident, cfg, _run, "kernel.matmul")
+    return None if out is None else out.reshape(out_shape)
 
 
 # ------------------------------------------------- attention dispatch
@@ -384,7 +389,7 @@ def attention_decode_eligible(q, k_pages, v_pages, *, policy,
     """Trace-time eligibility of the paged decode-attention kernel.
 
     True iff: split bf16 policy; TPU backend or ``force``; decode-layout
-    shapes — q ``(B, H, hd)``, pools ``(NP, ps, Hkv, hd[v])`` with
+    shapes — q ``(B, H, hd)``, pools ``(NP, Hkv, ps, hd[v])`` with
     ``H % Hkv == 0``; a single page fits VMEM; the hatches are off
     (``REPRO_DISABLE_PALLAS`` wholesale, ``REPRO_DISABLE_PAGED_ATTN``
     granular); and — under an installed GSPMD mesh — the ``shard_map``
@@ -416,7 +421,7 @@ def _paged_reason(q, k_pages, v_pages, pol, cfg) -> str:
     if q.ndim != 3 or k_pages.ndim != 4 or v_pages.ndim != 4:
         return "shape-unsupported"
     B, H, hd = q.shape
-    NP, ps, Hkv, hd2 = k_pages.shape
+    NP, Hkv, ps, hd2 = k_pages.shape
     if (hd2 != hd or v_pages.shape[:3] != k_pages.shape[:3]
             or Hkv == 0 or H % Hkv):
         return "shape-unsupported"
@@ -439,7 +444,7 @@ def attention_decode(q, k_pages, v_pages, block_tables, lengths, *, policy,
     None for the gather-and-attend fallback.
 
     Called from ``models.layers.attention_decode_paged`` with one query
-    token per sequence slot: q ``(B, H, hd)``, pools ``(NP, ps, Hkv,
+    token per sequence slot: q ``(B, H, hd)``, pools ``(NP, Hkv, ps,
     hd[v])``, ``block_tables`` ``(B, maxp)`` i32, ``lengths`` ``(B,)`` i32
     counting valid tokens *including* the current one (whose K/V must
     already be written to its page).  ``window`` may be a traced scalar.
@@ -459,7 +464,7 @@ def attention_decode(q, k_pages, v_pages, block_tables, lengths, *, policy,
     mesh, plan = _mesh_plan_or_decline(
         lambda m: shmap.paged_plan(q.shape, k_pages.shape, m), cfg)
     B, H, hd = q.shape
-    NP, ps, Hkv, _ = k_pages.shape
+    NP, Hkv, ps, _ = k_pages.shape
     ident = (pol.name, B, Hkv, H // Hkv, block_tables.shape[1], ps)
     if mesh is not None:
         if plan == "decline":         # eligibility screens this; graceful

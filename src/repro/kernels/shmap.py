@@ -8,7 +8,7 @@ fell back to the XLA term expansion.  This module closes that gap: it
 maps the framework's mesh conventions (``parallel/sharding.py``: batch on
 the ``pod``/``data`` axes, heads / hidden / sequence on ``model``) onto
 per-shard operand ``PartitionSpec``s and wraps each kernel call in
-``jax.experimental.shard_map.shard_map``, so every device runs the fused
+``jax.shard_map``, so every device runs the fused
 kernel on *its shard only* and GSPMD inserts at most a reshard on entry.
 
 Three plan builders — :func:`matmul_plan`, :func:`attention_plan`,
@@ -58,7 +58,6 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro import numerics
@@ -327,11 +326,11 @@ class PagedPlan:
 
 
 def paged_plan(q_shape, pool_shape, mesh) -> PagedPlan | None:
-    """q ``(B, H, hd)``, pools ``(NP, ps, Hkv, hd)`` -> plan or None."""
+    """q ``(B, H, hd)``, pools ``(NP, Hkv, ps, hd)`` -> plan or None."""
     if not _known_axes_only(mesh):
         return None
     B, H, _ = q_shape
-    Hkv = pool_shape[2]
+    Hkv = pool_shape[1]
     dp = _dp_axes(mesh)
     dsize = _dp_size(mesh)
     msize = _axis_size(mesh, "model")
@@ -353,7 +352,7 @@ def paged_plan(q_shape, pool_shape, mesh) -> PagedPlan | None:
         Hkvl = Hkv // msize
     return PagedPlan(
         q_spec=P(bdim, hdim, None),
-        pool_spec=P(None, None, hdim, None),
+        pool_spec=P(None, hdim, None, None),
         bt_spec=P(bdim, None),
         len_spec=P(bdim),
         out_spec=P(bdim, hdim, None),
@@ -394,8 +393,9 @@ def sharded_matmul(a, b, *, policy: str, mesh, cfg=None,
         return out
 
     _bump("matmul")
-    return shard_map(body, mesh=mesh, in_specs=(plan.a_spec, plan.b_spec),
-                     out_specs=plan.out_spec, check_rep=False)(a, b)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(plan.a_spec, plan.b_spec),
+                         out_specs=plan.out_spec, check_vma=False)(a, b)
 
 
 def _pos_2d(pos, B, n):
@@ -448,11 +448,11 @@ def sharded_attention(q, k, v, q_pos=None, k_pos=None, *, policy: str,
                               block=block, interpret=interpret)
 
     _bump("attention")
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(plan.q_spec, plan.k_spec, plan.v_spec, plan.qp_spec,
                   plan.kp_spec, P()),
-        out_specs=plan.out_spec, check_rep=False)(q, k, v, qp, kp, win)
+        out_specs=plan.out_spec, check_vma=False)(q, k, v, qp, kp, win)
 
 
 def sharded_paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
@@ -472,7 +472,7 @@ def sharded_paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
         plan = paged_plan(q.shape, k_pages.shape, mesh)
     assert plan is not None, (q.shape, k_pages.shape, dict(mesh.shape))
     B, H, hd = q.shape
-    NP, ps, Hkv, _ = k_pages.shape
+    NP, Hkv, ps, _ = k_pages.shape
     hdv = v_pages.shape[3]
     Bl, Hkvl = plan.local
     maxp = block_tables.shape[1]
@@ -494,9 +494,9 @@ def sharded_paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
                                     pages_per_step=g, interpret=interpret)
 
     _bump("paged")
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(plan.q_spec, plan.pool_spec, plan.pool_spec, plan.bt_spec,
                   plan.len_spec, P()),
-        out_specs=plan.out_spec, check_rep=False)(
+        out_specs=plan.out_spec, check_vma=False)(
             q, k_pages, v_pages, bt, lens, win)

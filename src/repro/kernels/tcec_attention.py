@@ -47,18 +47,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.policy import PrecisionPolicy, get_policy
-from .tcec_matmul import VMEM_BUDGET, _split_tile
+from .tcec_matmul import VMEM_BUDGET, _split_tile, compiler_params
 
 # Must match models.layers.NEG_INF: the additive mask bias is part of the
 # bit-parity contract with the pdot-composition fallback (finite, so
 # fully-masked rows produce garbage instead of NaN — same as the fallback).
 NEG_INF = -2.0e38
-
-
-def _compiler_params(semantics):
-    """pltpu.CompilerParams across jax versions (TPUCompilerParams pre-0.5)."""
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(dimension_semantics=semantics)
 
 
 def _contract(a, b, dims, upcast: bool):
@@ -116,8 +110,8 @@ def _attn_kernel(win_ref, q_ref, k_ref, v_ref, qp_ref, kp_ref, o_ref,
         for acc in accs:
             acc[...] = jnp.zeros_like(acc)
 
-    qp = qp_ref[0]                       # (bq,) i32 query positions
-    kp = kp_ref[0]                       # (bk,) i32 key positions
+    qp = qp_ref[...]                     # (bq, 1) i32 query positions
+    kp = kp_ref[...]                     # (1, bk) i32 key positions
     win = win_ref[0]                     # traced scalar; 0 = unlimited
 
     # ---- block-level skip: a K block masked for every (q, k) pair in the
@@ -140,10 +134,10 @@ def _attn_kernel(win_ref, q_ref, k_ref, v_ref, qp_ref, kp_ref, o_ref,
             s = cap * jnp.tanh(s / cap)
         # additive f32 mask bias — models.layers._mask_bias, tile-local,
         # plus masking of the zero-padded KV tail
-        qpr = jnp.broadcast_to(qp[None, :], (rep, bq)).reshape(rows, 1)
-        d = qpr - kp[None, :]                        # (rows, bk)
+        qpr = jnp.concatenate([qp] * rep, axis=0)    # (rows, 1)
+        d = qpr - kp                                 # (rows, bk)
         ok = (d >= 0) if causal else jnp.ones_like(d, dtype=bool)
-        ok = jnp.logical_and(ok, jnp.where(win > 0, d < win, True))
+        ok = jnp.logical_and(ok, jnp.logical_or(win <= 0, d < win))
         cols = col0 + jax.lax.broadcasted_iota(jnp.int32, (rows, bk), 1)
         ok = jnp.logical_and(ok, cols < t_actual)
         s = s + jnp.where(ok, 0.0, NEG_INF).astype(jnp.float32)
@@ -248,7 +242,7 @@ def tcec_attention_pallas(q, k, v, q_pos, k_pos, window, *, policy_name: str,
 
     kwargs = {}
     if not interpret:
-        kwargs["compiler_params"] = _compiler_params(
+        kwargs["compiler_params"] = compiler_params(
             ("parallel", "parallel", "parallel", "arbitrary"))
 
     kern = functools.partial(
@@ -267,7 +261,7 @@ def tcec_attention_pallas(q, k, v, q_pos, k_pos, window, *, policy_name: str,
                          lambda b, h, qi, ki: (b, h, ki, 0)),
             pl.BlockSpec((1, 1, bk, hdv),
                          lambda b, h, qi, ki: (b, h, ki, 0)),
-            pl.BlockSpec((1, bq), lambda b, h, qi, ki: (0, qi)),
+            pl.BlockSpec((bq, 1), lambda b, h, qi, ki: (qi, 0)),
             pl.BlockSpec((1, bk), lambda b, h, qi, ki: (0, ki)),
         ],
         out_specs=pl.BlockSpec((1, 1, rep, bq, hdv),
@@ -335,7 +329,7 @@ def tcec_attention(q, k, v, q_pos=None, k_pos=None, *,
             p = p[0]
         return _pad_axis(p.reshape(1, n), 1, mult)
 
-    qp = pos_row(q_pos, S, bq)
+    qp = pos_row(q_pos, S, bq).reshape(-1, 1)       # column: rows of s
     kp = pos_row(k_pos, T, bk)
     win = jnp.asarray(0 if window is None else window, jnp.int32).reshape(1)
 

@@ -39,7 +39,19 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.policy import PrecisionPolicy, get_policy
 
-VMEM_BUDGET = 64 * 1024 * 1024  # v5e VMEM ~128MB/core; leave headroom
+# v5e has 128 MiB of VMEM per core, but Mosaic's default scoped limit is
+# 16 MiB, which the larger blocks exceed.  VMEM_BUDGET filters blocks by the
+# working set the ``*_vmem_bytes`` models estimate; VMEM_LIMIT is the scoped
+# limit every kernel asks Mosaic for, with room for the temporaries the
+# estimates leave out.
+VMEM_BUDGET = 64 * 1024 * 1024
+VMEM_LIMIT = 100 * 1024 * 1024
+
+
+def compiler_params(semantics):
+    """Mosaic parameters shared by the TCEC kernels."""
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=VMEM_LIMIT)
 
 # Activations the fused epilogue supports. These are the exact jnp/jax.nn
 # callables the reference (unfused) model path uses, so fusing an epilogue
@@ -197,8 +209,7 @@ def tcec_matmul_pallas(a: jax.Array, b: jax.Array, bias: jax.Array | None = None
 
     kwargs = {}
     if not interpret:
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=semantics)
+        kwargs["compiler_params"] = compiler_params(semantics)
 
     kern = functools.partial(
         _kernel, policy=policy, k_steps=k_steps, k_axis=len(grid) - 1,

@@ -53,9 +53,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.policy import PrecisionPolicy, get_policy
-from .tcec_matmul import VMEM_BUDGET, _split_tile  # noqa: F401 (re-export)
-from .tcec_attention import (NEG_INF, _QK_DIMS, _compiler_params,
-                             _pv_parts, _round_up, _tcec_product)
+from .tcec_matmul import VMEM_BUDGET, compiler_params
+from .tcec_attention import (NEG_INF, _QK_DIMS, _pv_parts, _round_up,
+                             _tcec_product)
 
 
 def _paged_kernel(tbl_ref, len_ref, win_ref, q_ref, *refs,
@@ -66,8 +66,7 @@ def _paged_kernel(tbl_ref, len_ref, win_ref, q_ref, *refs,
     v_refs = refs[pages:2 * pages]
     o_ref = refs[2 * pages]
     m_ref, l_ref, *accs = refs[2 * pages + 1:]
-    ps, hd = k_refs[0].shape[1], k_refs[0].shape[3]
-    hdv = v_refs[0].shape[3]
+    ps = k_refs[0].shape[2]
     cols = pages * ps
     b = pl.program_id(0)
     i = pl.program_id(2)
@@ -95,10 +94,10 @@ def _paged_kernel(tbl_ref, len_ref, win_ref, q_ref, *refs,
     def _step():
         q = q_ref[0, 0].astype(jnp.float32)              # (rep, hd)
         kt = jnp.concatenate(
-            [k_refs[j][0, :, 0, :].astype(jnp.float32) for j in range(pages)],
+            [k_refs[j][0, 0].astype(jnp.float32) for j in range(pages)],
             axis=0)                                      # (cols, hd)
         vt = jnp.concatenate(
-            [v_refs[j][0, :, 0, :].astype(jnp.float32) for j in range(pages)],
+            [v_refs[j][0, 0].astype(jnp.float32) for j in range(pages)],
             axis=0)                                      # (cols, hdv)
         s = _tcec_product(q, kt, _QK_DIMS, policy, upcast)
         s = s / jnp.float32(sm_denom)
@@ -109,7 +108,7 @@ def _paged_kernel(tbl_ref, len_ref, win_ref, q_ref, *refs,
         # stale finite-or-not garbage that must not leak through
         pos = col0 + jax.lax.broadcasted_iota(jnp.int32, (rep, cols), 1)
         ok = pos <= cur
-        ok = jnp.logical_and(ok, jnp.where(win > 0, cur - pos < win, True))
+        ok = jnp.logical_and(ok, jnp.logical_or(win <= 0, cur - pos < win))
         s = jnp.where(ok, s, jnp.float32(NEG_INF))
 
         if n_steps == 1:
@@ -174,8 +173,8 @@ def tcec_paged_attention_pallas(q, k_pages, v_pages, block_tables, lengths,
                                 interpret: bool = False):
     """Paged decode attention on pool-layout operands.
 
-    q: (B, Hkv, rep, hd) f32; k_pages: (NP, ps, Hkv, hd); v_pages:
-    (NP, ps, Hkv, hdv) (any float dtype — pages are upcast per tile);
+    q: (B, Hkv, rep, hd) f32; k_pages: (NP, Hkv, ps, hd); v_pages:
+    (NP, Hkv, ps, hdv) (any float dtype — pages are upcast per tile);
     block_tables: (B, maxp) i32 page indices, ``maxp`` a multiple of
     ``pages_per_step`` (pad rows with any allocated page — masked);
     lengths: (B,) i32 valid tokens *including* the current one; window:
@@ -185,7 +184,7 @@ def tcec_paged_attention_pallas(q, k_pages, v_pages, block_tables, lengths,
     policy = get_policy(policy_name)
     assert not policy.is_plain(), "paged kernel is for split policies"
     B, Hkv, rep2, hd = q.shape
-    NP, ps, Hkv2, hd2 = k_pages.shape
+    NP, Hkv2, ps, hd2 = k_pages.shape
     hdv = v_pages.shape[3]
     assert rep2 == rep and Hkv2 == Hkv and hd2 == hd, (q.shape, k_pages.shape)
     assert v_pages.shape[:3] == k_pages.shape[:3], (k_pages.shape,
@@ -199,10 +198,12 @@ def tcec_paged_attention_pallas(q, k_pages, v_pages, block_tables, lengths,
     n_steps = maxp // G
     grid = (B, Hkv, n_steps)
 
+    # one (ps, width) page of one KV head per block: the trailing block
+    # dims equal the pool's, which Mosaic's tiling accepts at any size
     def page_spec(j, width):
         return pl.BlockSpec(
-            (1, ps, 1, width),
-            lambda b, h, i, tbl, lens, win, j=j: (tbl[b, i * G + j], 0, h, 0))
+            (1, 1, ps, width),
+            lambda b, h, i, tbl, lens, win, j=j: (tbl[b, i * G + j], h, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -220,7 +221,7 @@ def tcec_paged_attention_pallas(q, k_pages, v_pages, block_tables, lengths,
     )
     kwargs = {}
     if not interpret:
-        kwargs["compiler_params"] = _compiler_params(
+        kwargs["compiler_params"] = compiler_params(
             ("parallel", "parallel", "arbitrary"))
     kern = functools.partial(
         _paged_kernel, policy=policy, rep=rep, pages=G, n_steps=n_steps,
@@ -243,14 +244,14 @@ def tcec_paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     """Public entry: fused paged decode attention on model-layout operands.
 
     q: (B, H, hd) — one query token per sequence slot; k_pages/v_pages:
-    (NP, ps, Hkv, hd[v]) page pools; block_tables: (B, maxp) i32;
+    (NP, Hkv, ps, hd[v]) head-major page pools; block_tables: (B, maxp) i32;
     lengths: (B,) i32 valid tokens including the current one (the current
     token's K/V must already be written to its page).  GQA via
     ``H = rep * Hkv``; ``window`` may be a traced scalar (0 = unlimited).
     Returns (B, H, hdv) f32.
     """
     B, H, hd = q.shape
-    NP, ps, Hkv, _ = k_pages.shape
+    NP, Hkv, ps, _ = k_pages.shape
     hdv = v_pages.shape[3]
     assert H % Hkv == 0, (H, Hkv)
     rep = H // Hkv

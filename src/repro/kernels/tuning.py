@@ -31,6 +31,11 @@ variables):
     ``~/.cache/repro/tcec_autotune.json``).
   * ``tune="force"`` — measure even off-TPU (tests/bench).
   * ``tune="off"``   — never measure; heuristic only.
+
+Measurement never runs inside a trace (``jit``, ``grad``, ``shard_map``):
+there a kernel call returns tracers, so the timer would time tracing.  A
+lookup from inside a trace that misses the cache gets the heuristic block,
+unpersisted; sweep outside the trace to persist a winner.
 """
 from __future__ import annotations
 
@@ -244,6 +249,11 @@ def cache_key(B: int, M: int, N: int, K: int, policy_name: str,
 
 # ------------------------------------------------------------- measurement
 
+def _tracing() -> bool:
+    """True inside a JAX trace, where array ops return tracers."""
+    return isinstance(jnp.zeros(()), jax.core.Tracer)
+
+
 def _should_measure(cfg=None) -> bool:
     mode = (cfg or numerics.active()).tune
     if mode == "off":
@@ -282,12 +292,12 @@ def _autotune_protocol(key: str, heuristic, candidates, measure,
     cache hit -> heuristic short-circuit (never persisted, so a later TPU
     process still measures) -> candidate sweep -> persist the winner.
     ``heuristic``/``candidates`` are thunks; ``measure`` is ``block -> ms``
-    or None (meaning: measurement unavailable here)."""
+    or None (meaning: measurement unavailable here, as inside a trace)."""
     hit = cache.get(key)
     if hit is not None:
         return tuple(hit["block"]), {**hit, "source": "cache"}
 
-    if measure is None:
+    if measure is None or _tracing():
         block = heuristic()
         entry = {"block": list(block), "ms": None, "source": "heuristic"}
         cache.put(key, entry, persist=False)
@@ -506,8 +516,8 @@ def _measure_paged(B, Hkv, rep, maxp, ps, hd, hdv, policy_name, g,
         interpret = jax.default_backend() != "tpu"
     NP = max(2, B * maxp + 1)
     q = jnp.ones((B, Hkv * rep, hd), jnp.float32)
-    kp = jnp.ones((NP, ps, Hkv, hd), jnp.bfloat16)
-    vp = jnp.ones((NP, ps, Hkv, hdv), jnp.bfloat16)
+    kp = jnp.ones((NP, Hkv, ps, hd), jnp.bfloat16)
+    vp = jnp.ones((NP, Hkv, ps, hdv), jnp.bfloat16)
     bt = (jnp.arange(B * maxp, dtype=jnp.int32).reshape(B, maxp) % (NP - 1)
           + 1)
     lens = jnp.full((B,), maxp * ps, jnp.int32)

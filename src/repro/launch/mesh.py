@@ -6,19 +6,30 @@ dry-run builds 512 placeholder devices."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes.  The framework shards through
+    GSPMD propagation plus explicit ``shard_map`` regions, so its meshes
+    must not default to Explicit axes (jax >= 0.7), under which an
+    unannotated gather such as the embedding lookup is a type error."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
-    """Tiny mesh over whatever devices exist (tests / examples)."""
+    """``(devices / model, model)`` ("data", "model") mesh over every
+    device of this process."""
     n = len(jax.devices())
     assert n % model == 0
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 # Compiler flags a real TPU launch would set for collective/compute overlap

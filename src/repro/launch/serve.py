@@ -141,6 +141,8 @@ def main():
     from repro import obs
     obs.add_cli_flags(ap)
     args = ap.parse_args()
+    from repro.launch import compile_cache
+    compile_cache.enable()
 
     import contextlib
     mesh_scope = contextlib.nullcontext()

@@ -40,6 +40,8 @@ def main():
     from repro import obs
     obs.add_cli_flags(ap)
     args = ap.parse_args()
+    from repro.launch import compile_cache
+    compile_cache.enable()
 
     with numerics.cli_context(args), obs.cli_session(args):
         _main(args)

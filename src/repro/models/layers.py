@@ -367,7 +367,7 @@ def attention_decode_paged(p, x, cfg, pool, block_tables, lengths, window=0):
     """One-token decode against a paged KV cache (serving engine).
 
     x: (B, 1, d_model) — one token per sequence slot; pool: ``{"k": (NP,
-    ps, Hkv, hd), "v": (NP, ps, Hkv, hdv)}`` page arrays shared across
+    Hkv, ps, hd), "v": (NP, Hkv, ps, hdv)}`` page arrays shared across
     slots; block_tables: (B, maxp) i32 page indices per slot; lengths:
     (B,) i32 tokens already cached per slot (the current token's position).
 
@@ -382,12 +382,12 @@ def attention_decode_paged(p, x, cfg, pool, block_tables, lengths, window=0):
     B = x.shape[0]
     positions = lengths[:, None].astype(jnp.int32)            # (B, 1)
     q, k, v = _project_qkv(p, x, cfg, positions)
-    ps = pool["k"].shape[1]
+    ps = pool["k"].shape[2]
     maxp = block_tables.shape[1]
     page = block_tables[jnp.arange(B), lengths // ps]         # (B,)
     off = lengths % ps
-    ck = pool["k"].at[page, off].set(k[:, 0].astype(pool["k"].dtype))
-    cv = pool["v"].at[page, off].set(v[:, 0].astype(pool["v"].dtype))
+    ck = pool["k"].at[page, :, off].set(k[:, 0].astype(pool["k"].dtype))
+    cv = pool["v"].at[page, :, off].set(v[:, 0].astype(pool["v"].dtype))
     fused = dispatch.attention_decode(q[:, 0], ck, cv, block_tables,
                                       lengths + 1, policy=cfg.mix_policy,
                                       window=window,
@@ -395,9 +395,10 @@ def attention_decode_paged(p, x, cfg, pool, block_tables, lengths, window=0):
     if fused is not None:
         o = fused[:, None].astype(jnp.float32)                # (B, 1, H, hdv)
     else:
-        Hkv, hd = ck.shape[2], ck.shape[3]
-        kg = ck[block_tables].reshape(B, maxp * ps, Hkv, hd)
-        vg = cv[block_tables].reshape(B, maxp * ps, Hkv, cv.shape[3])
+        def gather(pages):                     # -> (B, maxp * ps, Hkv, d)
+            g = jnp.swapaxes(pages[block_tables], 2, 3)
+            return g.reshape((B, maxp * ps) + g.shape[3:])
+        kg, vg = gather(ck), gather(cv)
         o = _decode_attend(q, kg, vg, cfg, lengths, window)
     out = pdot("bshk,hkd->bsd", o, p["wo"], cfg.policy)
     return out, {"k": ck, "v": cv}

@@ -424,16 +424,18 @@ def init_paged_cache(cfg, num_pages: int, page_size: int,
     token axis replaced by a (num_pages, page_size) page pool shared
     across sequences (per-sequence block tables live in the serving
     engine).  Page 0 is the engine's scrap page — inactive slots write
-    into it."""
+    into it.  Attention K/V pools are head-major, ``(NP, Hkv, ps, hd)``,
+    so one page of one head is a ``(ps, hd)`` tile the paged kernel
+    fetches whole."""
     def one(_):
         if cfg.use_mla:
             return {"c_kv": jnp.zeros((num_pages, page_size,
                                        cfg.kv_lora_rank), dtype),
                     "k_rope": jnp.zeros((num_pages, page_size,
                                          cfg.qk_rope_dim), dtype)}
-        return {"k": jnp.zeros((num_pages, page_size, cfg.n_kv_heads,
+        return {"k": jnp.zeros((num_pages, cfg.n_kv_heads, page_size,
                                 cfg.head_dim), dtype),
-                "v": jnp.zeros((num_pages, page_size, cfg.n_kv_heads,
+                "v": jnp.zeros((num_pages, cfg.n_kv_heads, page_size,
                                 cfg.head_dim), dtype)}
     nd = cfg.first_dense_layers
     n_moe = (cfg.n_layers - nd) if cfg.n_experts else 0

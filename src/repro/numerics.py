@@ -31,7 +31,7 @@ Three layers live here:
 * **Trace correctness** — the active config travels as part of the jit
   cache key: every distinct config is interned to a *config epoch*, and
   :func:`use` installs the epoch in JAX's trace context (via
-  ``jax.experimental.xla_metadata``, with a cache-clearing fallback).
+  ``jax.experimental.xla_metadata``).
   Entering or exiting a context therefore deterministically re-lowers
   previously-jitted shapes instead of silently reusing a stale dispatch
   decision; re-entering a config that was already traced reuses its
@@ -411,31 +411,15 @@ def _epoch_scope(cfg: NumericsConfig):
     """Context manager keying JAX trace caches on ``cfg``'s epoch.
 
     Uses ``jax.experimental.xla_metadata`` (part of jax's trace context,
-    so tracing caches and executable caches both key on it).  When that
-    API is unavailable the fallback clears jit caches on entry and exit —
-    strictly correct, just not cached across re-entries.
+    so tracing caches and executable caches both key on it).
 
     Epoch 0 (the env-default config) is tagged too: a restore-to-default
     context nested inside a non-default one must *replace* the enclosing
     epoch, or its traces would be keyed (and later cache-hit) under the
     outer config.
     """
-    eid = config_epoch(cfg)
-    try:
-        from jax.experimental.xla_metadata import set_xla_metadata
-        return set_xla_metadata(repro_numerics_epoch=str(eid))
-    except ImportError:                       # pragma: no cover - old jax
-        return _clearing_scope()
-
-
-@contextlib.contextmanager
-def _clearing_scope():                        # pragma: no cover - old jax
-    import jax
-    jax.clear_caches()
-    try:
-        yield
-    finally:
-        jax.clear_caches()
+    from jax.experimental.xla_metadata import set_xla_metadata
+    return set_xla_metadata(repro_numerics_epoch=str(config_epoch(cfg)))
 
 
 @contextlib.contextmanager

@@ -88,16 +88,18 @@ _obs_metrics.register_source("serving/engine", _engines_source)
 
 
 def _pool_spec(shape, mesh):
-    """PartitionSpec for one page-pool leaf ``(..., Hkv, hd)``: KV heads
-    on ``model`` when divisible (the paged plan's layout), else head_dim
-    (always a multiple of 16 in the zoo — ``parallel/sharding.py``'s
-    cache convention), else replicated."""
+    """PartitionSpec for one page-pool leaf: K/V leaves ``(nL, NP, Hkv,
+    ps, hd)`` put KV heads on ``model`` when divisible (the paged plan's
+    layout), else head_dim (always a multiple of 16 in the zoo —
+    ``parallel/sharding.py``'s cache convention); latent leaves ``(nL,
+    NP, ps, d)`` shard ``d``; anything that does not divide stays
+    replicated."""
     from jax.sharding import PartitionSpec as P
     msize = mesh.shape["model"] if "model" in mesh.axis_names else 1
     dims = [None] * len(shape)
-    if msize > 1 and len(shape) >= 2:
-        if shape[-2] % msize == 0:
-            dims[-2] = "model"
+    if msize > 1:
+        if len(shape) == 5 and shape[2] % msize == 0:
+            dims[2] = "model"
         elif shape[-1] % msize == 0:
             dims[-1] = "model"
     return P(*dims)
@@ -231,7 +233,7 @@ class Engine:
 
     def _pool_shardings(self):
         """Multi-device pool layout: shard each page pool's KV-head dim
-        (axis -2) on the ``model`` axis when it divides — the same layout
+        on the ``model`` axis when it divides — the same layout
         ``kernels/shmap.py``'s paged plan shards the kernel over, so the
         decode step never reshards the cache.  When the head count does
         not divide (kv_heads < model size), fall back to sharding head_dim

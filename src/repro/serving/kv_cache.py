@@ -31,10 +31,22 @@ from .errors import PagePoolError
 
 DEFAULT_PAGE_SIZE = 16
 
-# The pool arrays are the dominant serving allocation and every update
-# rebinds them, so donate the input buffers for in-place updates — except
-# on CPU, where XLA doesn't implement donation and would warn per compile.
-_DONATE = () if jax.default_backend() == "cpu" else (0,)
+
+def _jit_donating_pools(fn):
+    """``jax.jit`` that donates the pools (argument 0).  The pool arrays are
+    the dominant serving allocation and every update rebinds them, so the
+    update runs in place — except on CPU, where XLA doesn't implement
+    donation and would warn per compile.  The backend is asked at the
+    first call, never at import."""
+    @functools.cache
+    def jitted():
+        donate = () if jax.default_backend() == "cpu" else (0,)
+        return jax.jit(fn, donate_argnums=donate)
+
+    @functools.wraps(fn)
+    def call(*args):
+        return jitted()(*args)
+    return call
 
 
 class PagePool:
@@ -145,12 +157,23 @@ class PagePool:
 
 
 # ------------------------------------------------------- device helpers
+#
+# Pool leaves are (nL, NP, Hkv, ps, hd) for attention K/V (head-major, see
+# ``models.lm.init_paged_cache``) and (nL, NP, ps, d) for latent caches;
+# prefill and scratch leaves are token-major, (nL, B, T, ...).
 
-@functools.partial(jax.jit, donate_argnums=_DONATE)
+
+def _swap_head_token(x):
+    """(nL, n, ps, Hkv, hd) <-> (nL, n, Hkv, ps, hd) for K/V page blocks;
+    latent page blocks (no head axis) pass through."""
+    return jnp.swapaxes(x, 2, 3) if x.ndim == 5 else x
+
+
+@_jit_donating_pools
 def write_prompt_pages(pools, kv, pages):
     """Scatter a sequence-level prefill's K/V into allocated pages.
 
-    pools: the ``init_paged_cache`` tree, leaves (nL, NP, ps, ...);
+    pools: the ``init_paged_cache`` tree;
     kv: the matching ``prefill`` tree, leaves (nL, B, P, ...) with ``P``
     a multiple of ``ps`` (right-pad prompts to the page size — padded
     positions are masked by the sequence length and overwritten as decode
@@ -160,9 +183,9 @@ def write_prompt_pages(pools, kv, pages):
 
     def one(pool, k):
         nL, B, P = k.shape[:3]
-        ps = pool.shape[2]
+        ps = pool.shape[-2]
         kp = k.reshape((nL, B * (P // ps), ps) + k.shape[3:])
-        return pool.at[:, flat].set(kp.astype(pool.dtype))
+        return pool.at[:, flat].set(_swap_head_token(kp).astype(pool.dtype))
 
     return jax.tree.map(one, pools, kv)
 
@@ -173,12 +196,12 @@ def load_pages_into_scratch(scratch, pools, pages):
     scratch cache (chunked prefill over a prefix-cache hit).
 
     scratch: an ``init_cache(batch=1, ...)`` tree, leaves (nL, 1, T, ...);
-    pools: the page-pool tree, leaves (nL, NP, ps, ...); pages: (n,) i32
+    pools: the page-pool tree; pages: (n,) i32
     with ``n * ps <= T``.  The gathered tokens land at positions
     ``[0, n * ps)`` — the prefix the tail chunks attend over.
     """
     def one(s, pool):
-        g = pool[:, pages]                            # (nL, n, ps, ...)
+        g = _swap_head_token(pool[:, pages])          # (nL, n, ps, ...)
         g = g.reshape((g.shape[0], 1, g.shape[1] * g.shape[2]) + g.shape[3:])
         return jax.lax.dynamic_update_slice(s, g.astype(s.dtype),
                                             (0,) * s.ndim)
@@ -186,11 +209,11 @@ def load_pages_into_scratch(scratch, pools, pages):
     return jax.tree.map(one, scratch, pools)
 
 
-@functools.partial(jax.jit, donate_argnums=_DONATE)
+@_jit_donating_pools
 def write_span_pages(pools, scratch, start, pages):
     """Scatter one chunk's token span from the scratch cache into pages.
 
-    pools: leaves (nL, NP, ps, ...); scratch: leaves (nL, 1, T, ...);
+    pools: the page-pool tree; scratch: leaves (nL, 1, T, ...);
     start: i32 token index of the span (page-aligned); pages: (n,) i32 —
     the span covers tokens ``[start, start + n * ps)``.  The f32 scratch
     values cast to the pool dtype exactly as a monolithic prefill's
@@ -199,16 +222,16 @@ def write_span_pages(pools, scratch, start, pages):
     """
     def one(pool, s):
         nL = s.shape[0]
-        ps = pool.shape[2]
+        ps = pool.shape[-2]
         n = pages.shape[0]
         span = jax.lax.dynamic_slice_in_dim(s[:, 0], start, n * ps, axis=1)
         sp = span.reshape((nL, n, ps) + span.shape[2:])
-        return pool.at[:, pages].set(sp.astype(pool.dtype))
+        return pool.at[:, pages].set(_swap_head_token(sp).astype(pool.dtype))
 
     return jax.tree.map(one, pools, scratch)
 
 
-@functools.partial(jax.jit, donate_argnums=_DONATE)
+@_jit_donating_pools
 def permute_pages(pools, perm):
     """Apply a defrag permutation to the device page arrays.
 

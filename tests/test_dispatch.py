@@ -80,11 +80,24 @@ def test_min_dim_gate_and_shape_rules():
             _rand((8, 32), 0), _rand((32, 16), 1), pol,
             (((1,), (0,)), ((), ())))
         assert small is None          # below min_dim -> XLA
+    x, w = _rand((4, 8, 128), 0), _rand((128, 128), 1)
+    h, wo = _rand((2, 8, 4, 32), 2), _rand((4, 32, 128), 3)
     with numerics.use(force=True, interpret=True, min_dim=0):
-        multi_m = dispatch.maybe_dispatch(
-            _rand((4, 8, 128), 0), _rand((128, 128), 1), pol,
-            (((2,), (0,)), ((), ())))
-        assert multi_m is None        # a.ndim != nb+2 -> XLA
+        # free and contracted dims collapse onto one (M, K) @ (K, N) GEMM
+        multi_m = dispatch.maybe_dispatch(x, w, pol, (((2,), (0,)),
+                                                      ((), ())))
+        multi_k = dispatch.maybe_dispatch(h, wo, pol, (((2, 3), (0, 1)),
+                                                       ((), ())))
+        two_batch = dispatch.maybe_dispatch(
+            _rand((2, 2, 8, 128), 4), _rand((2, 2, 128, 128), 5), pol,
+            (((3,), (2,)), ((0, 1), (0, 1))))
+        assert two_batch is None      # more than one batch dim -> XLA
+    ref_m = _xla(lambda: pdot("bsd,df->bsf", x, w, pol.name))
+    ref_k = _xla(lambda: pdot("bshk,hkd->bsd", h, wo, pol.name))
+    for got, ref in ((multi_m, ref_m), (multi_k, ref_k)):
+        assert got is not None and got.shape == ref.shape
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=1e-6, atol=1e-5)
 
 
 def test_explicit_cfg_argument_wins_over_ambient():
@@ -306,6 +319,39 @@ def test_autotune_reuse_across_processes(tmp_path):
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, cwd="/root/repo", timeout=300)
     assert f"SOURCE cache {blk}" in r.stdout, (r.stdout, r.stderr)
+
+
+def test_autotune_never_measures_inside_a_trace(tmp_path, monkeypatch):
+    """Inside ``jit`` a timed kernel call returns tracers, so the tuner
+    would time tracing: it must give the heuristic block, unpersisted,
+    even under ``tune="force"``."""
+    calls = []
+    monkeypatch.setattr(tuning, "_measure_block",
+                        lambda *a, **kw: calls.append(a) or 1.0)
+    path = tmp_path / "tune.json"
+    a = jnp.asarray(np.random.default_rng(0).standard_normal((256, 256)),
+                    jnp.float32)
+    seen = {}
+
+    def f(x):
+        seen["direct"] = tuning.autotune(
+            1, 512, 512, 512, "tcec_bf16x6", cache=tuning.BlockCache(
+                path=str(path)), measure=lambda blk: calls.append(blk) or 1.0)
+        return numerics.matmul(x, x, policy="tcec_bf16x6")
+
+    with numerics.use(force=True, interpret=True, min_dim=0, tune="force",
+                      tune_cache=str(path)):
+        out = jax.jit(f)(a)
+    assert calls == []
+    blk, meta = seen["direct"]
+    assert meta["source"] == "heuristic"
+    assert blk == tuning.heuristic_block(512, 512, 512, "tcec_bf16x6")
+    entries = (json.load(open(path))["entries"] if path.exists() else {})
+    assert not any(e.get("source") == "measured" for e in entries.values())
+    with numerics.use(enabled=False):
+        ref = numerics.matmul(a, a, policy="tcec_bf16x6")
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-5, atol=1e-4)
 
 
 def test_heuristic_fallback_not_persisted(tmp_path):

@@ -13,6 +13,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_smoke_config
+from repro.launch.mesh import make_mesh
 from repro.launch import specs as S
 from repro.optim import adamw
 from repro.parallel import sharding as shd
@@ -92,16 +93,16 @@ def test_compressed_psum_error_feedback():
     """bf16 all-reduce with error feedback: telescoping residuals keep the
     long-run mean unbiased (vs plain bf16 rounding which drifts)."""
     from repro.parallel.collectives import compressed_psum, zeros_like_residual
-    mesh = jax.make_mesh((1,), ("d",))
+    mesh = make_mesh((1,), ("d",))
     g = {"w": jnp.full((256,), 1.0 + 2.0**-12)}  # not bf16-representable
 
     def run_steps(n):
         res = zeros_like_residual(g)
         total = jnp.zeros_like(g["w"])
         from functools import partial
-        from jax.experimental.shard_map import shard_map
 
-        @partial(shard_map, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()))
+        @partial(jax.shard_map, mesh=mesh, in_specs=(P(), P()),
+                 out_specs=(P(), P()))
         def one(gw, rw):
             red, nr = compressed_psum({"w": gw}, {"w": rw}, "d")
             return red["w"], nr["w"]
@@ -131,6 +132,7 @@ SUBPROC_DP = textwrap.dedent("""
     from repro.models import get_model
     from repro.optim import adamw
     from repro.launch.step import make_train_step
+    from repro.launch.mesh import make_mesh
 
     cfg = get_smoke_config("qwen3-0.6b")
     opt = adamw.OptConfig(lr=1e-3)
@@ -146,7 +148,7 @@ SUBPROC_DP = textwrap.dedent("""
     # single device
     s1, m1 = jax.jit(step)(state, batch)
     # 8-way DP
-    mesh = jax.make_mesh((8, 1), ("data", "model"))
+    mesh = make_mesh((8, 1), ("data", "model"))
     bs = {k: NamedSharding(mesh, P("data")) for k in batch}
     batch_sharded = {k: jax.device_put(v, bs[k]) for k, v in batch.items()}
     s8, m8 = jax.jit(step)(state, batch_sharded)

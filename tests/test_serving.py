@@ -208,8 +208,8 @@ def test_scheduler_grow_fails_only_when_alone_and_dry():
 def _paged_case(B=3, Hkv=2, rep=4, hd=64, hdv=64, ps=8, maxp=5, seed=0):
     rng = np.random.default_rng(seed)
     NP = 1 + B * maxp
-    kp = jnp.asarray(rng.standard_normal((NP, ps, Hkv, hd)), jnp.bfloat16)
-    vp = jnp.asarray(rng.standard_normal((NP, ps, Hkv, hdv)), jnp.bfloat16)
+    kp = jnp.asarray(rng.standard_normal((NP, Hkv, ps, hd)), jnp.bfloat16)
+    vp = jnp.asarray(rng.standard_normal((NP, Hkv, ps, hdv)), jnp.bfloat16)
     q = jnp.asarray(rng.standard_normal((B, Hkv * rep, hd)), jnp.float32)
     bt = jnp.asarray(
         rng.permutation(np.arange(1, NP)).reshape(B, maxp), jnp.int32)
@@ -219,7 +219,7 @@ def _paged_case(B=3, Hkv=2, rep=4, hd=64, hdv=64, ps=8, maxp=5, seed=0):
 
 def _gather(pages, bt):
     B, maxp = bt.shape
-    g = pages[bt]
+    g = jnp.swapaxes(pages[bt], 2, 3)          # (B, maxp, ps, Hkv, d)
     return g.reshape(B, maxp * g.shape[2], g.shape[3], g.shape[4])
 
 

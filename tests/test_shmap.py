@@ -107,12 +107,12 @@ def test_attention_plan_heads_then_qseq():
 
 def test_paged_plan_heads_on_model_tables_local():
     mesh = FakeMesh(data=2, model=2)
-    plan = shmap.paged_plan((2, 8, 64), (9, 8, 4, 64), mesh)
-    assert plan.pool_spec == P(None, None, "model", None)
+    plan = shmap.paged_plan((2, 8, 64), (9, 4, 8, 64), mesh)
+    assert plan.pool_spec == P(None, "model", None, None)
     assert plan.bt_spec == P("data", None)       # device-local block tables
     assert plan.len_spec == P("data")
     assert plan.local == (1, 2)
-    assert shmap.paged_plan((2, 8, 64), (9, 8, 3, 64), mesh) is None
+    assert shmap.paged_plan((2, 8, 64), (9, 3, 8, 64), mesh) is None
 
 
 # ----------------------------------------------- routing + counters (1 dev)
@@ -188,12 +188,16 @@ def test_pool_spec_head_dim_fallback():
     head_dim (the parallel/sharding.py cache convention), else
     replicated — pool capacity scales with TP either way."""
     from repro.serving.engine import _pool_spec
-    assert _pool_spec((9, 8, 4, 64), FakeMesh(data=2, model=2)) \
-        == P(None, None, "model", None)
-    assert _pool_spec((9, 8, 2, 64), FakeMesh(data=1, model=4)) \
-        == P(None, None, None, "model")      # Hkv=2 < msize=4 -> head_dim
-    assert _pool_spec((9, 8, 3, 7), FakeMesh(data=1, model=4)) \
-        == P(None, None, None, None)     # nothing divides -> replicated
+    # K/V leaves are (nL, NP, Hkv, ps, hd)
+    assert _pool_spec((2, 9, 4, 8, 64), FakeMesh(data=2, model=2)) \
+        == P(None, None, "model", None, None)
+    assert _pool_spec((2, 9, 2, 8, 64), FakeMesh(data=1, model=4)) \
+        == P(None, None, None, None, "model")  # Hkv=2 < msize=4 -> head_dim
+    assert _pool_spec((2, 9, 3, 8, 7), FakeMesh(data=1, model=4)) \
+        == P(None, None, None, None, None)  # nothing divides -> replicated
+    # latent leaves (nL, NP, ps, d) shard d
+    assert _pool_spec((2, 9, 8, 64), FakeMesh(data=1, model=4)) \
+        == P(None, None, None, "model")
 
 
 def test_epilogue_fusion_declines_under_mesh():
@@ -316,15 +320,16 @@ SUBPROC_BATTERY = textwrap.dedent("""
     import repro
     from repro import numerics
     from repro.kernels import shmap
+    from repro.launch.mesh import make_mesh
     from repro.parallel import ctx
 
     def rand(shape, seed):
         return jnp.asarray(
             np.random.default_rng(seed).standard_normal(shape), jnp.float32)
 
-    mesh2 = jax.make_mesh((1, 2), ("data", "model"))
-    mesh4 = jax.make_mesh((2, 2), ("data", "model"))
-    mesh8 = jax.make_mesh((2, 4), ("data", "model"))
+    mesh2 = make_mesh((1, 2), ("data", "model"))
+    mesh4 = make_mesh((2, 2), ("data", "model"))
+    mesh8 = make_mesh((2, 4), ("data", "model"))
 
     with numerics.use(force=True, interpret=True, min_dim=0,
                       block=(128, 128, 128), attn_block=(128, 128),
@@ -382,7 +387,7 @@ SUBPROC_BATTERY = textwrap.dedent("""
         q1 = rand((2, 256, 2, 64), 9)          # Hkv=1: forces qseq on 4-way
         k1 = rand((2, 256, 1, 64), 10)
         v1 = rand((2, 256, 1, 64), 11)
-        mesh_q = jax.make_mesh((2, 4), ("data", "model"))
+        mesh_q = make_mesh((2, 4), ("data", "model"))
         plan = shmap.attention_plan(q1.shape, k1.shape, mesh_q)
         assert plan.mode == "qseq", plan
         refq = repro.attention(q1, k1, v1, policy="tcec_bf16x6", window=37)
@@ -403,8 +408,8 @@ SUBPROC_BATTERY = textwrap.dedent("""
         rng = np.random.default_rng(12)
         B, Hkv, rep, hd, ps, maxp, NP = 2, 4, 2, 64, 8, 4, 9
         qd = rand((B, Hkv * rep, hd), 13)
-        kp = jnp.asarray(rng.standard_normal((NP, ps, Hkv, hd)), jnp.bfloat16)
-        vp = jnp.asarray(rng.standard_normal((NP, ps, Hkv, hd)), jnp.bfloat16)
+        kp = jnp.asarray(rng.standard_normal((NP, Hkv, ps, hd)), jnp.bfloat16)
+        vp = jnp.asarray(rng.standard_normal((NP, Hkv, ps, hd)), jnp.bfloat16)
         bt = jnp.asarray(rng.permutation(8).reshape(B, maxp) + 1, jnp.int32)
         lens = jnp.asarray([25, 30], jnp.int32)
         refp = kd.attention_decode(qd, kp, vp, bt, lens,

@@ -1,8 +1,14 @@
 """End-to-end behaviour tests for the paper's system: the accuracy claim
 measured through a full train step, and the serving path."""
+import os
+import pathlib
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import get_smoke_config
 from repro.data.pipeline import DataConfig, device_batch
@@ -51,3 +57,25 @@ def test_serving_generates_deterministically():
     b = generate(cfg, params, prompts, gen_len=6)
     assert a.shape == (2, 6)
     assert jnp.array_equal(a, b)
+
+
+@pytest.mark.parametrize("env_dir", [None, "given"])
+def test_compile_cache_dir(tmp_path, env_dir):
+    """The launch binaries keep JAX's compile cache where
+    ``JAX_COMPILATION_CACHE_DIR`` says and set nothing else; without it, in
+    a fixed, git-ignored directory of the checkout."""
+    root = pathlib.Path(__file__).resolve().parents[1]
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = str(root / "src")
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    code = ("import jax; from repro.launch import compile_cache; "
+            "d = compile_cache.enable(); "
+            "print(d, jax.config.jax_compilation_cache_dir)")
+    r = subprocess.run([sys.executable, "-c", code], env=env, cwd=tmp_path,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr
+    want = str(tmp_path) if env_dir else str(root / ".jax_cache")
+    assert r.stdout.split() == [want, want]
+    assert ".jax_cache/" in (root / ".gitignore").read_text().split()
