@@ -4,11 +4,14 @@
     gauges, and fixed-bucket histograms with labels, snapshot/reset/diff,
     and read-time *sources* folding the pre-existing counter surfaces in
     (circuit breaker, faults fire-log, live engines' stats).
-  * **tracing** (:mod:`repro.obs.trace`) — context-scoped
-    ``obs.trace()`` spans through the serving request lifecycle,
+  * **tracing** (:mod:`repro.obs.trace`) — one span per engine phase
+    (and per garbage collection), recorded in two sinks: always in the
+    JAX profiler's trace (``jax.profiler.TraceAnnotation``, on the clock
+    the device ops share, args as event stats), and in the tracer a
+    context-scoped ``obs.trace()`` installs, with the request lifecycle,
     exportable as Chrome-trace/Perfetto JSON or JSONL via
-    :func:`export`; while active, the engine records queue-wait / TTFT /
-    TPOT into ``serving/latency/*`` histograms.
+    :func:`export`; while a tracer is active, the engine records
+    queue-wait / TTFT / TPOT into ``serving/latency/*`` histograms.
   * **dispatch explainability** (:mod:`repro.obs.explain`) — every
     trace-time kernel-routing decision records which rule declined (or
     accepted); :func:`explain` reports them.
@@ -18,7 +21,7 @@
 
 See docs/observability.md for a guided tour.  This package stays
 JAX-free at import time (the engine and dispatcher import it at module
-scope).
+scope); the profiler sink imports ``jax.profiler`` on first use.
 """
 from __future__ import annotations
 

@@ -243,10 +243,6 @@ def observe(name: str, v: float, buckets=None, **labels):
     histogram(name, buckets).observe(v, **labels)
 
 
-def set_gauge(name: str, v: float, **labels):
-    gauge(name).set(v, **labels)
-
-
 # -------------------------------------------------------------- sources
 #
 # A source is a zero-arg callable returning a flat {str: number} dict —
@@ -257,11 +253,6 @@ def set_gauge(name: str, v: float, **labels):
 def register_source(name: str, fn):
     with _LOCK:
         _SOURCES[name] = fn
-
-
-def unregister_source(name: str):
-    with _LOCK:
-        _SOURCES.pop(name, None)
 
 
 def read_sources() -> dict[str, dict]:

@@ -1,18 +1,33 @@
-"""Context-scoped span tracing in the Chrome trace event format.
+"""Spans on two clocks, and the Chrome-trace event buffer.
+
+:func:`span` is the one helper every instrumentation point calls.  It
+records one phase of the program in two sinks:
+
+  * the JAX profiler's own trace, always, as a
+    ``jax.profiler.TraceAnnotation`` (``StepTraceAnnotation`` when given
+    a ``step_num``), on the clock the device ops share, with the span's
+    args as event stats.  With no profiler session running this costs
+    one C++ ``TraceMe`` check;
+  * the :class:`Tracer` installed by ``trace()``, when there is one, as a
+    Chrome-trace complete event on that tracer's clock.
+
+:func:`install_gc_hook` adds every garbage collection to both sinks as a
+``python.gc`` span, from a ``gc.callbacks`` hook installed once per
+process.  ``jax.profiler`` is imported on first use, so importing this
+module pulls in no JAX.
 
 ``trace()`` installs a :class:`Tracer` for its dynamic extent (innermost
 wins — the same precedence discipline as ``numerics.use`` and
 ``faults.use``); instrumented code asks :func:`current` for the active
-tracer and does nothing when there isn't one, so tracing-off costs one
-thread-local read per instrumentation point and zero device work.
+tracer, and request events and latency samples are skipped when there
+isn't one.
 
-Events follow the Chrome trace event format (the JSON Perfetto and
-``chrome://tracing`` load directly):
+Tracer events follow the Chrome trace event format (the JSON Perfetto
+and ``chrome://tracing`` load directly):
 
-  * ``span(name)`` — a ``ph:"X"`` complete event with microsecond
+  * ``Tracer.span(name)`` — a ``ph:"X"`` complete event with microsecond
     ``ts``/``dur``.  The context manager yields a mutable args dict, so
-    annotations computed *inside* the block (batch occupancy, clock)
-    land on the exported event.
+    annotations computed *inside* the block land on the exported event.
   * ``instant(name)`` — a ``ph:"i"`` thread-scoped instant.
   * ``async_begin/instant/end(name, id)`` — ``ph:"b"/"n"/"e"`` async
     events keyed by id: one per *request*, spanning its whole lifetime
@@ -25,12 +40,13 @@ latency *distributions* (queue-wait, TTFT, TPOT) are not derived from
 the events — instrumentation records them straight into
 ``obs.metrics`` histograms while the tracer is active.
 
-The clock is injectable (``Tracer(clock=...)``) so tests drive spans
-deterministically; the default is ``time.perf_counter``.
+The tracer's clock is injectable (``Tracer(clock=...)``) so tests drive
+spans deterministically; the default is ``time.perf_counter``.
 """
 from __future__ import annotations
 
 import contextlib
+import gc
 import json
 import threading
 import time
@@ -75,9 +91,14 @@ class Tracer:
         try:
             yield a
         finally:
-            self._emit({"name": name, "cat": cat, "ph": "X", "ts": t0,
-                        "dur": self._ts() - t0, "pid": 0,
-                        "tid": self._tid(), "args": a})
+            self.complete(name, t0, cat, a)
+
+    def complete(self, name: str, t0: float, cat: str, args: dict):
+        """A complete event from ``t0`` (this tracer's microseconds) to
+        now."""
+        self._emit({"name": name, "cat": cat, "ph": "X", "ts": t0,
+                    "dur": self._ts() - t0, "pid": 0,
+                    "tid": self._tid(), "args": args})
 
     def instant(self, name: str, cat: str = "event", **args):
         self._emit({"name": name, "cat": cat, "ph": "i", "s": "t",
@@ -162,3 +183,99 @@ def last() -> Tracer | None:
         return cur
     with _LAST_LOCK:
         return _LAST
+
+
+# ------------------------------------------------------ two-sink spans
+
+_PROFILER = None
+
+
+def _profiler():
+    """``jax.profiler``, imported on first use."""
+    global _PROFILER
+    if _PROFILER is None:
+        from jax import profiler
+        _PROFILER = profiler
+    return _PROFILER
+
+
+class _Span:
+    __slots__ = ("_note", "_obs", "args")
+
+    def __init__(self, name: str, step_num, args: dict):
+        prof = _profiler()
+        if step_num is None:
+            self._note = prof.TraceAnnotation(name, **args)
+        else:
+            self._note = prof.StepTraceAnnotation(name, step_num=step_num,
+                                                  **args)
+            args["step_num"] = step_num
+        tr = current()
+        self._obs = None if tr is None else tr.span(name, "engine", **args)
+        self.args = args
+
+    def __enter__(self) -> dict:
+        self._note.__enter__()
+        if self._obs is None:
+            return self.args
+        return self._obs.__enter__()
+
+    def __exit__(self, *exc):
+        try:
+            if self._obs is not None:
+                self._obs.__exit__(*exc)
+        finally:
+            self._note.__exit__(*exc)
+        return False
+
+
+def span(name: str, *, step_num: int | None = None, **args) -> _Span:
+    """A span around one phase of the program, in the profiler's trace
+    always and in the active :class:`Tracer` when there is one.
+
+    ``args`` become the profiler event's stats and the tracer event's
+    args; they are built whether or not either sink records, so pass
+    only what is cheap to compute.  ``step_num`` makes the profiler
+    event a step annotation.  The context manager yields the tracer
+    event's mutable args dict (a throwaway dict when no tracer is
+    active)."""
+    return _Span(name, step_num, args)
+
+
+# ------------------------------------------------ garbage collections
+
+_GC_OPEN = None     # (profiler note or None, tracer or None, tracer ts)
+
+
+def _on_gc(phase: str, info: dict):
+    global _GC_OPEN
+    if phase == "start":
+        prof = _profiler()
+        note = None
+        if prof.TraceAnnotation.is_enabled():
+            note = prof.TraceAnnotation("python.gc",
+                                        generation=info["generation"])
+            note.__enter__()
+        tr = current()
+        if note is not None or tr is not None:
+            _GC_OPEN = (note, tr, None if tr is None else tr._ts())
+        return
+    if _GC_OPEN is None:
+        return
+    note, tr, t0 = _GC_OPEN
+    _GC_OPEN = None
+    if tr is not None:
+        tr.complete("python.gc", t0, "gc",
+                    {"generation": info["generation"],
+                     "collected": info["collected"]})
+    if note is not None:
+        note.__exit__(None, None, None)
+
+
+def install_gc_hook():
+    """Record every garbage collection as a ``python.gc`` span (arg
+    ``generation``) in both sinks.  Idempotent: the hook is installed
+    once per process however often this is called."""
+    if _on_gc not in gc.callbacks:
+        _profiler()         # never import from inside a collection
+        gc.callbacks.append(_on_gc)

@@ -42,7 +42,6 @@ fault-injectable via :mod:`repro.faults`.
 """
 from __future__ import annotations
 
-import contextlib
 import functools
 import weakref
 
@@ -54,6 +53,7 @@ from repro import faults, numerics
 from repro.models import get_model
 from repro.obs import metrics as _obs_metrics
 from repro.obs.trace import current as _current_tracer
+from repro.obs.trace import install_gc_hook, span as _span
 from . import sampling
 from .errors import (EngineOverloaded, FinishReason, RequestRejected,
                      RequestResult)
@@ -73,14 +73,9 @@ _LIVE_ENGINES: "weakref.WeakSet[Engine]" = weakref.WeakSet()
 def _engines_source() -> dict:
     out: dict[str, int] = {}
     for eng in list(_LIVE_ENGINES):
-        stats = {**eng._stats, "clock": eng.clock,
-                 "prefills": eng.n_prefills,
-                 "prefill_chunks": eng.n_prefill_chunks,
-                 "decode_steps": eng.n_decode_steps,
-                 "preemptions": eng.sched.n_preemptions,
-                 "parks": eng.sched.n_parks}
-        for k, v in stats.items():
-            out[k] = out.get(k, 0) + int(v)
+        for k, v in eng.stats().items():
+            if k != "breaker":          # process-wide: its own source
+                out[k] = out.get(k, 0) + int(v)
     return out
 
 
@@ -230,6 +225,7 @@ class Engine:
         self.n_prefills = 0
         self.n_prefill_chunks = 0
         _LIVE_ENGINES.add(self)
+        install_gc_hook()
 
     def _pool_shardings(self):
         """Multi-device pool layout: shard each page pool's KV-head dim
@@ -260,18 +256,12 @@ class Engine:
 
     # ------------------------------------------------------- observability
     #
-    # Everything below is gated on an active repro.obs tracer: with no
-    # trace() context installed there are no spans, no wall-clock reads,
-    # and no histogram writes — the engine's hot loop is unchanged (the
-    # overhead test pins zero extra jitted traces with tracing off).
-
-    def _span(self, name: str, **args):
-        """A tracer span around one engine phase, or a no-op context
-        yielding a throwaway args dict when tracing is off."""
-        tr = _current_tracer()
-        if tr is None:
-            return contextlib.nullcontext(dict(args))
-        return tr.span(name, cat="engine", **args)
+    # Each engine phase is an ``obs.trace.span``: always a profiler
+    # annotation (one C++ check when no profiler session runs), and a
+    # tracer event when a repro.obs tracer is active.  Request events and
+    # latency histograms below are gated on an active tracer: with none
+    # installed there are no wall-clock reads and no histogram writes
+    # (the overhead test pins zero extra jitted traces either way).
 
     @staticmethod
     def _observe_latency(name: str, seconds: float):
@@ -335,7 +325,10 @@ class Engine:
 
     # ----------------------------------------------------------- prefill
 
-    def _admit_and_prefill(self):
+    def _admit(self) -> list[Request]:
+        """Admission: finish requests that outgrew a slot, admit what the
+        scheduler allows and set up their chunked prefills; returns the
+        admitted requests."""
         # a preempted request may have *generated* its way past the per-slot
         # cap (add_request only guards prompts): finish it from the queue —
         # re-admitting would need more pages than a block-table row holds
@@ -363,6 +356,11 @@ class Engine:
                 if req.t_enqueue is not None and req.n_preemptions == 0:
                     self._observe_latency("queue_wait_s",
                                           now - req.t_enqueue)
+        return admitted
+
+    def _prefill_admitted(self, admitted: list[Request]):
+        """Monolithic prefill of the RUNNING admissions, one batched call
+        per padded length, and each request's first token."""
         ps = self.pool.page_size
         # same padded length -> one batched prefill call (PREFILLING
         # admissions advance chunk-by-chunk in _prefill_chunk_step instead)
@@ -374,7 +372,8 @@ class Engine:
             padded = max(1, -(-len(seq) // ps)) * ps
             groups.setdefault(padded, []).append(req)
         for padded, reqs in sorted(groups.items()):
-            with self._span("prefill", batch=len(reqs), padded=padded):
+            with _span("engine.prefill", batch=len(reqs),
+                       tokens=len(reqs) * padded):
                 toks = np.zeros((len(reqs), padded), np.int32)
                 for i, req in enumerate(reqs):
                     toks[i, :len(req.full_sequence)] = req.full_sequence
@@ -404,7 +403,9 @@ class Engine:
                                              :self.cfg.vocab_size],
                                       jnp.float32)
                     req.key, sub = jax.random.split(req.key)
-                    tok = int(sampling.sample_one(row, req.params, sub))
+                    tok = sampling.sample_one(row, req.params, sub)
+                    with _span("engine.sync"):
+                        tok = int(tok)
                     self._accept_token(req, tok)
 
     # a request whose prefill fails this many times finishes with
@@ -530,7 +531,8 @@ class Engine:
         padded = max(1, -(-plen // ps)) * ps
         start = req.prefill_done
         C = self.chunk_tokens or (padded - start)
-        with self._span("prefill.chunk", rid=req.rid, start=start, chunk=C):
+        with _span("engine.prefill_chunk", rid=req.rid, start=start,
+                   tokens=C):
             # pages this chunk scatters back: whole pages in
             # [start, min(start+C, padded)) — the grid-rounded final
             # chunk's pure-padding tail is never materialized
@@ -580,7 +582,9 @@ class Engine:
             row = jnp.asarray(logits[0, plen - 1 - start,
                                      :self.cfg.vocab_size], jnp.float32)
             req.key, sub = jax.random.split(req.key)
-            tok = int(sampling.sample_one(row, req.params, sub))
+            tok = sampling.sample_one(row, req.params, sub)
+            with _span("engine.sync"):
+                tok = int(tok)
             self._accept_token(req, tok)
         return True
 
@@ -701,7 +705,7 @@ class Engine:
                    if r.state is RequestState.RUNNING]
         if not running:
             return None
-        with self._span("decode", batch=len(running)):
+        with _span("engine.dispatch", batch=len(running)):
             buf = self._staging[self.n_decode_steps % 2]
             for name, host in buf.items():
                 np.copyto(host, getattr(self, name))
@@ -729,8 +733,9 @@ class Engine:
         running, args = inflight["running"], inflight["args"]
         prev_keys = inflight["prev_keys"]
         toks, finite = inflight["toks"], inflight["finite"]
-        with self._span("decode.consume", batch=len(running)):
-            finite = np.asarray(finite)
+        with _span("engine.consume", batch=len(running)):
+            with _span("engine.sync"):
+                finite = np.asarray(finite)
             bad = [r for r in running if not finite[r.slot]]
             if bad and self.numerics_config.guard:
                 # one-shot re-run of the whole step under the XLA-fallback
@@ -750,19 +755,23 @@ class Engine:
                     toks, finite, pools, keys = self._decode(
                         args[0], self.pools, *args[1:], prev_keys,
                         jnp.asarray(self._poison_mask()))
-                finite = np.asarray(finite)
+                with _span("engine.sync"):
+                    finite = np.asarray(finite)
                 self.pools, self.keys = pools, keys
-            toks = np.asarray(toks)
-            for req in running:
-                if not finite[req.slot]:
-                    # the fallback tripped too (or the guard is off): fail
-                    # THIS request; its batch neighbours are unharmed
-                    self._stats["numerics_errors"] += 1
-                    self._finish(req, FinishReason.ERROR)
-                    continue
-                self.lengths[req.slot] += 1  # its input token is now cached
-                req.key = self.keys[req.slot]
-                self._accept_token(req, int(toks[req.slot]))
+            with _span("engine.sync"):
+                toks = np.asarray(toks)
+            with _span("engine.accept"):
+                for req in running:
+                    if not finite[req.slot]:
+                        # the fallback tripped too (or the guard is off):
+                        # fail THIS request; its batch neighbours are
+                        # unharmed
+                        self._stats["numerics_errors"] += 1
+                        self._finish(req, FinishReason.ERROR)
+                        continue
+                    self.lengths[req.slot] += 1  # input token now cached
+                    req.key = self.keys[req.slot]
+                    self._accept_token(req, int(toks[req.slot]))
 
     # ------------------------------------------------------------- drive
 
@@ -789,7 +798,10 @@ class Engine:
         whatever is in flight — under the construction-time numerics and
         mesh scopes.  Sync mode (default) consumes the dispatch inline;
         async mode leaves it in flight until the next step."""
-        with self._scopes(), self._span("engine.step") as sp:
+        # step_num: the clock tick this step takes
+        with self._scopes(), _span("engine.step", step_num=self.clock + 1,
+                                   running=len(self.sched.running),
+                                   waiting=len(self.sched.waiting)):
             if self._inflight is not None:
                 inflight, self._inflight = self._inflight, None
                 self._decode_consume(inflight)
@@ -797,20 +809,19 @@ class Engine:
             spec = faults.poke("decode.slow")
             if spec is not None:         # injected slowdown: burn ticks
                 self.clock += max(1, spec.arg)
-            self._expire_deadlines()
-            self._admit_and_prefill()
+            with _span("engine.admit"):
+                self._expire_deadlines()
+                admitted = self._admit()
+            self._prefill_admitted(admitted)
             self._prefill_chunk_step()
-            self._ensure_pages()
+            with _span("engine.ensure_pages"):
+                self._ensure_pages()
             inflight = self._decode_dispatch()
             if inflight is not None:
                 if self.async_sched:
                     self._inflight = inflight
                 else:
                     self._decode_consume(inflight)
-            # annotated at exit: the span args dict is live until then
-            sp["clock"] = self.clock
-            sp["occupancy"] = len(self.sched.running)
-            sp["waiting"] = len(self.sched.waiting)
 
     def run(self, prompts=None, params=None) -> dict[int, RequestResult]:
         """Convenience driver: optionally enqueue ``prompts`` (with one

@@ -16,10 +16,17 @@ Pins the four telemetry layers' contracts:
     assumes RZ, which shifts the closed form by exactly one exponent);
   * the overhead bound — tracing off/on changes nothing about the
     engine's jitted traces (counted), and monitor off leaves the
-    contraction jaxpr callback-free.
+    contraction jaxpr callback-free;
+  * the profiler sink — every engine phase lands in a ``jax.profiler``
+    trace as a host event with its args as stats, nested as the engine
+    runs them, and garbage collections as ``python.gc``.
 """
+import gc
+import glob
 import json
 import os
+import subprocess
+import sys
 import threading
 
 import numpy as np
@@ -397,13 +404,15 @@ def _engine_fixture():
     return _ENGINE_CACHE["v"]
 
 
-def _run_engine(n_req=3, max_tokens=4):
+def _run_engine(n_req=3, max_tokens=4, prompt_len=6, chunk=0):
     from repro.serving import Engine, SamplingParams
     cfg, params = _engine_fixture()
-    engine = Engine(cfg, params, max_slots=4, num_pages=64, page_size=8)
+    engine = Engine(cfg, params, max_slots=4, num_pages=64, page_size=8,
+                    numerics_config=numerics.active().replace(
+                        chunked_prefill=chunk))
     rng = np.random.default_rng(8)
     for i in range(n_req):
-        engine.add_request(rng.integers(0, cfg.vocab_size, 6),
+        engine.add_request(rng.integers(0, cfg.vocab_size, prompt_len),
                            SamplingParams(max_tokens=max_tokens, seed=i))
     engine.run()
     return engine
@@ -431,11 +440,17 @@ def test_engine_trace_exports_request_lifecycle(tmp_path):
     assert len(admitted) == n_req
     steps = [e for e in evs
              if e["ph"] == "X" and e["name"] == "engine.step"]
-    assert steps and all("occupancy" in e["args"] and "clock" in e["args"]
+    assert steps and all({"running", "waiting", "step_num"} <= set(e["args"])
                          for e in steps)
-    assert any(e["name"] == "prefill" and e["args"]["batch"] >= 1
+    assert [e["args"]["step_num"] for e in steps] == \
+        list(range(1, len(steps) + 1))
+    assert any(e["name"] == "engine.prefill" and e["args"]["batch"] >= 1
                for e in evs if e["ph"] == "X")
-    assert any(e["name"] == "decode" for e in evs if e["ph"] == "X")
+    assert any(e["name"] == "engine.dispatch" and e["args"]["batch"] >= 1
+               for e in evs if e["ph"] == "X")
+    for name in ("engine.admit", "engine.ensure_pages", "engine.consume",
+                 "engine.sync", "engine.accept"):
+        assert any(e["name"] == name for e in evs if e["ph"] == "X"), name
     # latency histograms were fed while the tracer was active
     assert metrics.histogram("serving/latency/ttft_s").count() == n_req
     assert metrics.histogram("serving/latency/queue_wait_s").count() == n_req
@@ -444,9 +459,11 @@ def test_engine_trace_exports_request_lifecycle(tmp_path):
 
 
 def test_tracing_off_is_inert_and_adds_no_traces(monkeypatch):
-    """With no tracer installed the engine writes no spans and no latency
-    samples; and tracing on adds ZERO extra jitted traces — all
-    instrumentation is host-side (counted via the decode trace hook)."""
+    """With neither a tracer nor a profiler the engine writes no tracer
+    event and no latency samples; and tracing on adds ZERO extra jitted
+    traces — all instrumentation is host-side (counted via the decode
+    trace hook)."""
+    from jax.profiler import TraceAnnotation
     from repro.serving import engine as eng_mod
     obs.reset()
     counts = []
@@ -457,8 +474,13 @@ def test_tracing_off_is_inert_and_adds_no_traces(monkeypatch):
         return orig(*a, **kw)
 
     monkeypatch.setattr(eng_mod, "_decode_and_sample", counting)
+    # the most recently exited tracer is where a stray event would land
+    idle = Tracer()
+    monkeypatch.setattr(sys.modules["repro.obs.trace"], "_LAST", idle)
+    assert current() is None and not TraceAnnotation.is_enabled()
     _run_engine()                               # tracing off
     untraced = len(counts)
+    assert idle.events == [] and last() is idle
     assert metrics.histogram("serving/latency/ttft_s").count() == 0
     counts.clear()
     with trace() as tr:
@@ -473,6 +495,95 @@ def test_engine_stats_folded_into_snapshot():
     src = obs.snapshot()["sources"]["serving/engine"]
     assert src["decode_steps"] >= engine.n_decode_steps
     assert src["prefills"] >= engine.n_prefills
+    # the source sums every counter of Engine.stats() but the breaker,
+    # which is process-wide and folded in as kernels/guard
+    assert set(src) == set(engine.stats()) - {"breaker"}
+
+
+# ============================================== the profiler's clock
+
+def _profiled_spans(trace_dir) -> list:
+    """``(name, start_ns, end_ns, stats)`` of the host events whose names
+    start with ``engine.`` or ``python.``, sorted by start."""
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(os.path.join(str(trace_dir), "**",
+                                         "*.xplane.pb"), recursive=True))[-1]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(("engine.", "python.")):
+                    out.append((e.name, e.start_ns,
+                                e.start_ns + e.duration_ns, dict(e.stats)))
+    return sorted(out, key=lambda x: x[1])
+
+
+def _inside(inner, outers) -> bool:
+    return any(o[1] <= inner[1] and inner[2] <= o[2] for o in outers)
+
+
+def test_engine_spans_land_in_profiler_trace(tmp_path):
+    """Under a jax.profiler session, with no repro.obs tracer, each engine
+    phase is a host event nested as the engine runs it, with its args as
+    event stats; a garbage collection is a ``python.gc`` event."""
+    with jax.profiler.trace(str(tmp_path)):
+        engine = _run_engine(n_req=2, max_tokens=3, prompt_len=20, chunk=8)
+        gc.collect()
+    spans = _profiled_spans(tmp_path)
+    by = {}
+    for s in spans:
+        by.setdefault(s[0], []).append(s)
+    steps = by["engine.step"]
+    assert len(steps) == engine.clock
+    assert [s[3]["step_num"] for s in steps] == \
+        list(range(1, engine.clock + 1))
+    assert all({"running", "waiting"} <= set(s[3]) for s in steps)
+    # chunked prefill on: no monolithic prefill, 3 chunks per prompt
+    assert "engine.prefill" not in by
+    assert len(by["engine.prefill_chunk"]) == engine.n_prefill_chunks == 6
+    assert all(s[3]["tokens"] == 8 for s in by["engine.prefill_chunk"])
+    assert len(by["engine.dispatch"]) == engine.n_decode_steps
+    assert all(s[3]["batch"] >= 1 for s in by["engine.dispatch"])
+    # every blocking read: two per consume, one per finished prefill
+    consumes = by["engine.consume"]
+    assert all(sum(_inside(s, [c]) for s in by["engine.sync"]) == 2
+               for c in consumes)
+    assert all(_inside(s, consumes + by["engine.prefill_chunk"])
+               for s in by["engine.sync"])
+    assert len(by["engine.sync"]) == 2 * len(consumes) + 2
+    for name in ("engine.admit", "engine.ensure_pages", "engine.dispatch",
+                 "engine.consume", "engine.prefill_chunk"):
+        assert all(_inside(s, steps) for s in by[name]), name
+    assert all(_inside(s, consumes) for s in by["engine.accept"])
+    assert any(s[3]["generation"] == 2 for s in by["python.gc"])
+
+
+def test_gc_hook_installed_once():
+    """However many engines are built, one ``gc.callbacks`` hook records
+    each collection (here in the repro.obs tracer)."""
+    trace_mod = sys.modules["repro.obs.trace"]  # attr is the shadow fn
+    for _ in range(3):
+        _run_engine(n_req=1, max_tokens=1)
+    hooks = [cb for cb in gc.callbacks if cb is trace_mod._on_gc]
+    assert len(hooks) == 1
+    with trace() as tr:
+        gc.collect()
+    (ev,) = [e for e in tr.events if e["name"] == "python.gc"]
+    assert ev["ph"] == "X" and ev["args"]["generation"] == 2
+
+
+def test_obs_imports_no_jax():
+    """The engine and dispatcher import repro.obs at module scope, so it
+    stays JAX-free until a span first records."""
+    code = ("import sys, repro.obs; from repro.obs import trace; "
+            "assert 'jax' not in sys.modules, 'jax imported'")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, cwd=ROOT,
+                       env={**os.environ,
+                            "PYTHONPATH": os.path.join(ROOT, "src")})
+    assert r.returncode == 0, r.stderr[-2000:]
 
 
 # =============================================================== cli glue
