@@ -15,6 +15,7 @@ site                   effect at the instrumented callsite
 ``kernel.matmul``      fused GEMM dispatch raises (breaker sees a failure)
 ``kernel.attention``   fused flash-attention dispatch raises
 ``kernel.paged``       paged decode-attention dispatch raises
+``kernel.kv_write``    in-place decode K/V page write dispatch raises
 ``decode.nonfinite``   engine poisons one slot's decode logits to NaN
 ``decode.slow``        engine step burns extra deadline ticks
 ``prefill``            engine prefill raises (group is re-queued)
@@ -67,6 +68,8 @@ SITES: dict[str, str] = {
     "kernel.matmul": "fused GEMM dispatch raises FaultInjected",
     "kernel.attention": "fused flash-attention dispatch raises FaultInjected",
     "kernel.paged": "paged decode-attention dispatch raises FaultInjected",
+    "kernel.kv_write": "in-place decode K/V page write dispatch raises "
+                       "FaultInjected",
     "decode.nonfinite": "engine poisons a slot's decode logits to NaN "
                         "(arg = slot index, -1 = every slot)",
     "decode.slow": "engine step burns extra deadline ticks (arg = ticks)",

@@ -11,11 +11,13 @@ from .tcec_attention import (attn_vmem_bytes, tcec_attention,
                              tcec_attention_pallas)
 from .tcec_paged_attention import (paged_vmem_bytes, tcec_paged_attention,
                                    tcec_paged_attention_pallas)
+from .kv_page_write import kv_page_write, kv_page_write_pallas
 from . import dispatch, shmap, tuning
 
 __all__ = ["tcec_matmul", "pick_block", "tcec_matmul_ref", "tcec_bmm_ref",
            "matmul_f64", "tcec_matmul_pallas", "vmem_bytes", "VMEM_BUDGET",
            "EPILOGUE_ACTIVATIONS", "tcec_attention", "tcec_attention_pallas",
            "attn_vmem_bytes", "tcec_paged_attention",
-           "tcec_paged_attention_pallas", "paged_vmem_bytes", "dispatch",
+           "tcec_paged_attention_pallas", "paged_vmem_bytes",
+           "kv_page_write", "kv_page_write_pallas", "dispatch",
            "shmap", "tuning"]

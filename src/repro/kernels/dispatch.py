@@ -384,6 +384,13 @@ def attention(q, k, v, *, policy, q_pos=None, k_pos=None, causal: bool = True,
 # BlockSpecs and runs TCEC-split QK^T / P·V; the fallback (the caller's
 # gather + ``attention_decode`` math) is the verification oracle.
 
+def _layer_view(pages):
+    """The shape of one layer's pool: a stacked ``(L, NP, Hkv, ps, hd)``
+    pool is judged by its ``(NP, Hkv, ps, hd)`` entry (the kernels read
+    one entry per call, selected by a layer index)."""
+    return jax.ShapeDtypeStruct(pages.shape[1:], pages.dtype)
+
+
 def attention_decode_eligible(q, k_pages, v_pages, *, policy,
                               cfg=None) -> bool:
     """Trace-time eligibility of the paged decode-attention kernel.
@@ -439,13 +446,16 @@ def _paged_reason(q, k_pages, v_pages, pol, cfg) -> str:
 
 
 def attention_decode(q, k_pages, v_pages, block_tables, lengths, *, policy,
-                     window=0, softcap: float | None = None, cfg=None):
+                     window=0, softcap: float | None = None, layer=None,
+                     cfg=None):
     """Route a paged decode-attention call to the fused kernel, or return
     None for the gather-and-attend fallback.
 
     Called from ``models.layers.attention_decode_paged`` with one query
     token per sequence slot: q ``(B, H, hd)``, pools ``(NP, Hkv, ps,
-    hd[v])``, ``block_tables`` ``(B, maxp)`` i32, ``lengths`` ``(B,)`` i32
+    hd[v])`` — or, with ``layer`` given, a stack of them ``(L, NP, Hkv,
+    ps, hd[v])`` read at that entry (the decode step's in-place path) —
+    ``block_tables`` ``(B, maxp)`` i32, ``lengths`` ``(B,)`` i32
     counting valid tokens *including* the current one (whose K/V must
     already be written to its page).  ``window`` may be a traced scalar.
 
@@ -457,14 +467,15 @@ def attention_decode(q, k_pages, v_pages, block_tables, lengths, *, policy,
     from repro.core.policy import get_policy
     cfg = _cfg(cfg)
     pol = get_policy(policy)
-    if not attention_decode_eligible(q, k_pages, v_pages, policy=pol,
-                                     cfg=cfg):
+    kl, vl = ((k_pages, v_pages) if layer is None
+              else (_layer_view(k_pages), _layer_view(v_pages)))
+    if not attention_decode_eligible(q, kl, vl, policy=pol, cfg=cfg):
         return None
     from . import shmap
     mesh, plan = _mesh_plan_or_decline(
-        lambda m: shmap.paged_plan(q.shape, k_pages.shape, m), cfg)
+        lambda m: shmap.paged_plan(q.shape, kl.shape, m), cfg)
     B, H, hd = q.shape
-    NP, Hkv, ps, _ = k_pages.shape
+    NP, Hkv, ps, _ = kl.shape
     ident = (pol.name, B, Hkv, H // Hkv, block_tables.shape[1], ps)
     if mesh is not None:
         if plan == "decline":         # eligibility screens this; graceful
@@ -474,8 +485,8 @@ def attention_decode(q, k_pages, v_pages, block_tables, lengths, *, policy,
             "paged_attention", ident, cfg,
             lambda: shmap.sharded_paged_attention(
                 q, k_pages, v_pages, block_tables, lengths, policy=pol.name,
-                window=window, softcap=softcap, mesh=mesh, cfg=cfg,
-                plan=plan),
+                window=window, softcap=softcap, layer=layer, mesh=mesh,
+                cfg=cfg, plan=plan),
             "kernel.paged")
 
     def _run():
@@ -484,12 +495,95 @@ def attention_decode(q, k_pages, v_pages, block_tables, lengths, *, policy,
         if g is None:
             g = tuning.get_paged_block(B, Hkv, H // Hkv,
                                        block_tables.shape[1], ps, hd,
-                                       v_pages.shape[3], pol.name, cfg=cfg)
+                                       vl.shape[3], pol.name, cfg=cfg)
         return tcec_paged_attention(q, k_pages, v_pages, block_tables,
                                     lengths, policy=pol.name, window=window,
                                     softcap=softcap, pages_per_step=g,
-                                    interpret=cfg.interpret)
+                                    layer=layer, interpret=cfg.interpret)
     return _guarded("paged_attention", ident, cfg, _run, "kernel.paged")
+
+
+# ------------------------------------------------- in-place K/V write
+#
+# The decode step's in-place path (models/lm.py ``stack_decode_paged``)
+# carries every layer's pool as one stack through its layer scan: each
+# layer writes its token's K/V with the aliased page-write kernel
+# (kernels/kv_page_write.py), then reads the stack with the paged kernel
+# at its layer index, so no pool bytes move but the new token's.  The
+# path runs only where both kernels are eligible; otherwise the scan
+# slices each layer's pool and scatters with XLA, as before.
+
+def kv_write_reason(q, pool, *, policy, cfg=None) -> str:
+    """The in-place path's rule walk, recording nothing: ``"fused"`` when
+    the page-write kernel and the paged kernel are both eligible for
+    queries ``(B, H, hd)`` and one stack's pools ``{"k", "v"}``, each
+    head-major ``(L, NP, Hkv, ps, hd[v])``, else the declining rule.  The
+    rules are the paged kernel's (hatches, policy, backend, mesh plan,
+    VMEM) judged on one layer's pool: the write kernel's one-page blocks
+    need nothing more.  Other pools (MLA's latent ones) are
+    ``shape-unsupported``."""
+    from repro.core.policy import get_policy
+    cfg = _cfg(cfg)
+    pol = get_policy(policy)
+    if set(pool) != {"k", "v"}:
+        return "shape-unsupported"
+    k_pool, v_pool = pool["k"], pool["v"]
+    if (k_pool.ndim != 5 or v_pool.ndim != 5
+            or k_pool.shape[:4] != v_pool.shape[:4]):
+        return "shape-unsupported"
+    return _paged_reason(q, _layer_view(k_pool), _layer_view(v_pool), pol,
+                         cfg)
+
+
+def kv_write_eligible(q, pool, *, policy, cfg=None) -> bool:
+    """:func:`kv_write_reason` is ``"fused"``; a decline is recorded in
+    the explain table under ``kv_write`` (the launch records inside
+    ``_guarded``)."""
+    from repro.core.policy import get_policy
+    pol = get_policy(policy)
+    reason = kv_write_reason(q, pool, policy=pol, cfg=cfg)
+    if reason != "fused":
+        leaf = jax.tree.leaves(pool)[0]
+        _explain("kv_write", pol.name,
+                 (tuple(q.shape), tuple(leaf.shape)), reason)
+        return False
+    return True
+
+
+def kv_write(k_pool, v_pool, k_new, v_new, page, off, layer, *, policy,
+             cfg=None):
+    """Write one token per slot into stacked pools in place, or return
+    None for the caller's XLA scatter.
+
+    k_pool/v_pool ``(L, NP, Hkv, ps, hd[v])``; k_new/v_new ``(B, Hkv,
+    hd[v])``; page/off ``(B,)`` i32 (each slot's current page and row);
+    ``layer`` a traced scalar.  The caller has judged the path with
+    :func:`kv_write_eligible`; this runs the kernel behind the circuit
+    breaker (fault site ``kernel.kv_write``), per shard under a mesh.
+    ``policy`` only keys the explain entry: the write is exact."""
+    from repro.core.policy import get_policy
+    cfg = _cfg(cfg)
+    pol = get_policy(policy)
+    B, Hkv = k_new.shape[:2]
+    _, _, _, ps, hd = k_pool.shape
+    ident = (pol.name, B, Hkv, ps, hd)
+    from . import shmap
+    mesh, plan = _mesh_plan_or_decline(
+        lambda m: shmap.paged_plan((B, Hkv, hd), k_pool.shape[1:], m), cfg)
+    if plan == "decline":                 # eligibility screens this
+        _explain("kv_write", pol.name, ident[1:], "mesh-declined")
+        return None
+    if mesh is not None:
+        def _run():
+            return shmap.sharded_kv_write(k_pool, v_pool, k_new, v_new,
+                                          page, off, layer, mesh=mesh,
+                                          cfg=cfg, plan=plan)
+    else:
+        def _run():
+            from .kv_page_write import kv_page_write
+            return kv_page_write(k_pool, v_pool, k_new, v_new, page, off,
+                                 layer, interpret=cfg.interpret)
+    return _guarded("kv_write", ident, cfg, _run, "kernel.kv_write")
 
 
 # ------------------------------------------------- epilogue-fusion hook

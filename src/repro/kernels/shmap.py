@@ -67,7 +67,7 @@ from repro.obs import metrics as _metrics
 NAMESPACE = "shmap"
 
 #: the wrapped kernels (label values of ``kernels/shmap/calls``)
-KERNELS = ("matmul", "attention", "paged")
+KERNELS = ("matmul", "attention", "paged", "kv_write")
 
 
 def _bump(kernel: str):
@@ -455,25 +455,36 @@ def sharded_attention(q, k, v, q_pos=None, k_pos=None, *, policy: str,
         out_specs=plan.out_spec, check_vma=False)(q, k, v, qp, kp, win)
 
 
+def _pool_spec(plan: PagedPlan, ndim: int) -> P:
+    """The plan's pool spec for a per-layer ``(NP, Hkv, ps, hd)`` pool, or
+    for a stack of them with the leading layer axis unsharded."""
+    return P(*((None,) * (ndim - 4) + tuple(plan.pool_spec)))
+
+
 def sharded_paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
                             policy: str, window=0,
-                            softcap: float | None = None, mesh, cfg=None,
+                            softcap: float | None = None, layer=None,
+                            mesh, cfg=None,
                             plan: PagedPlan | None = None) -> jax.Array:
     """Run the fused paged decode-attention kernel per shard under ``mesh``.
 
     The pools shard on the KV-head dim (``model``); block tables and
     lengths stay device-local (replicated over ``model``), so the page
     gather on each device reads its own pool shard with the *same* table —
-    no cross-device page traffic.  Batch shards over the data axes.
+    no cross-device page traffic.  Batch shards over the data axes.  With
+    ``layer`` given the pools are stacks ``(L, NP, Hkv, ps, hd)``, the
+    layer axis unsharded and the index replicated.
     """
     from . import tuning
     cfg = _cfg(cfg)
+    stacked = layer is not None
+    pool_shape = k_pages.shape[1:] if stacked else k_pages.shape
     if plan is None:
-        plan = paged_plan(q.shape, k_pages.shape, mesh)
+        plan = paged_plan(q.shape, pool_shape, mesh)
     assert plan is not None, (q.shape, k_pages.shape, dict(mesh.shape))
     B, H, hd = q.shape
-    NP, Hkv, ps, _ = k_pages.shape
-    hdv = v_pages.shape[3]
+    NP, Hkv, ps, _ = pool_shape
+    hdv = v_pages.shape[-1]
     Bl, Hkvl = plan.local
     maxp = block_tables.shape[1]
     g = cfg.paged_block
@@ -483,20 +494,59 @@ def sharded_paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     interpret = _interpret(cfg)
     bt = jnp.asarray(block_tables, jnp.int32)
     lens = jnp.asarray(lengths, jnp.int32)
-    # the (possibly traced) window rides as an explicit replicated operand:
-    # shard_map bodies must not close over outer-trace values
+    # the (possibly traced) window and layer ride as explicit replicated
+    # operands: shard_map bodies must not close over outer-trace values
     win = jnp.asarray(0 if window is None else window, jnp.int32)
+    lyr = jnp.asarray(0 if layer is None else layer, jnp.int32)
 
-    def body(qs, kps, vps, bts, lns, w):
+    def body(qs, kps, vps, bts, lns, w, ly):
         from .tcec_paged_attention import tcec_paged_attention
         return tcec_paged_attention(qs, kps, vps, bts, lns, policy=policy,
                                     window=w, softcap=softcap,
-                                    pages_per_step=g, interpret=interpret)
+                                    pages_per_step=g,
+                                    layer=ly if stacked else None,
+                                    interpret=interpret)
 
+    pool = _pool_spec(plan, k_pages.ndim)
     _bump("paged")
     return jax.shard_map(
         body, mesh=mesh,
-        in_specs=(plan.q_spec, plan.pool_spec, plan.pool_spec, plan.bt_spec,
-                  plan.len_spec, P()),
+        in_specs=(plan.q_spec, pool, pool, plan.bt_spec, plan.len_spec, P(),
+                  P()),
         out_specs=plan.out_spec, check_vma=False)(
-            q, k_pages, v_pages, bt, lens, win)
+            q, k_pages, v_pages, bt, lens, win, lyr)
+
+
+def sharded_kv_write(k_pool, v_pool, k_new, v_new, page, off, layer, *,
+                     mesh, cfg=None, plan: PagedPlan | None = None):
+    """Run the in-place page write per shard under ``mesh``.
+
+    Stacked pools ``(L, NP, Hkv, ps, hd)`` shard their KV heads as the
+    paged plan does (layer axis unsharded); each device writes its heads
+    of every slot's token into its pool shard.  Pools are replicated over
+    the data axes, so every slot's token, page and row are replicated
+    there too (gathered from the batch shards on entry): each replica
+    writes the same tokens and the replicas stay equal.
+    """
+    from .kv_page_write import kv_page_write
+    cfg = _cfg(cfg)
+    B, Hkv, hd = k_new.shape
+    if plan is None:
+        plan = paged_plan((B, Hkv, hd), k_pool.shape[1:], mesh)
+    assert plan is not None, (k_new.shape, k_pool.shape, dict(mesh.shape))
+    interpret = _interpret(cfg)
+    hdim = plan.pool_spec[1]
+    pool = _pool_spec(plan, k_pool.ndim)
+    tok = P(None, hdim, None)
+
+    def body(kp, vp, kn, vn, pg, of, ly):
+        return kv_page_write(kp, vp, kn, vn, pg, of, ly,
+                             interpret=interpret)
+
+    _bump("kv_write")
+    return jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(pool, pool, tok, tok, P(None), P(None), P()),
+        out_specs=(pool, pool), check_vma=False)(
+            k_pool, v_pool, k_new, v_new, jnp.asarray(page, jnp.int32),
+            jnp.asarray(off, jnp.int32), jnp.asarray(layer, jnp.int32))

@@ -58,7 +58,7 @@ from .tcec_attention import (NEG_INF, _QK_DIMS, _pv_parts, _round_up,
                              _tcec_product)
 
 
-def _paged_kernel(tbl_ref, len_ref, win_ref, q_ref, *refs,
+def _paged_kernel(tbl_ref, len_ref, win_ref, layer_ref, q_ref, *refs,
                   policy: PrecisionPolicy, rep: int, pages: int,
                   n_steps: int, softcap: float | None, sm_denom: float,
                   upcast: bool):
@@ -66,7 +66,7 @@ def _paged_kernel(tbl_ref, len_ref, win_ref, q_ref, *refs,
     v_refs = refs[pages:2 * pages]
     o_ref = refs[2 * pages]
     m_ref, l_ref, *accs = refs[2 * pages + 1:]
-    ps = k_refs[0].shape[2]
+    ps = k_refs[0].shape[3]
     cols = pages * ps
     b = pl.program_id(0)
     i = pl.program_id(2)
@@ -94,10 +94,10 @@ def _paged_kernel(tbl_ref, len_ref, win_ref, q_ref, *refs,
     def _step():
         q = q_ref[0, 0].astype(jnp.float32)              # (rep, hd)
         kt = jnp.concatenate(
-            [k_refs[j][0, 0].astype(jnp.float32) for j in range(pages)],
+            [k_refs[j][0, 0, 0].astype(jnp.float32) for j in range(pages)],
             axis=0)                                      # (cols, hd)
         vt = jnp.concatenate(
-            [v_refs[j][0, 0].astype(jnp.float32) for j in range(pages)],
+            [v_refs[j][0, 0, 0].astype(jnp.float32) for j in range(pages)],
             axis=0)                                      # (cols, hdv)
         s = _tcec_product(q, kt, _QK_DIMS, policy, upcast)
         s = s / jnp.float32(sm_denom)
@@ -167,27 +167,30 @@ def paged_vmem_bytes(pages_per_step: int, page_size: int, rep: int, hd: int,
     "policy_name", "rep", "pages_per_step", "softcap", "sm_denom",
     "interpret"))
 def tcec_paged_attention_pallas(q, k_pages, v_pages, block_tables, lengths,
-                                window, *, policy_name: str, rep: int,
+                                window, layer, *, policy_name: str, rep: int,
                                 pages_per_step: int,
                                 softcap: float | None, sm_denom: float,
                                 interpret: bool = False):
     """Paged decode attention on pool-layout operands.
 
-    q: (B, Hkv, rep, hd) f32; k_pages: (NP, Hkv, ps, hd); v_pages:
-    (NP, Hkv, ps, hdv) (any float dtype — pages are upcast per tile);
-    block_tables: (B, maxp) i32 page indices, ``maxp`` a multiple of
-    ``pages_per_step`` (pad rows with any allocated page — masked);
-    lengths: (B,) i32 valid tokens *including* the current one; window:
-    (1,) i32 (0 = unlimited).  Returns (B, Hkv, rep, hdv) f32; rows with
-    ``length <= 0`` return zeros.
+    q: (B, Hkv, rep, hd) f32; k_pages: (L, NP, Hkv, ps, hd); v_pages:
+    (L, NP, Hkv, ps, hdv) — a stack of per-layer pools (any float dtype —
+    pages are upcast per tile); block_tables: (B, maxp) i32 page indices,
+    ``maxp`` a multiple of ``pages_per_step`` (pad rows with any allocated
+    page — masked); lengths: (B,) i32 valid tokens *including* the current
+    one; window: (1,) i32 (0 = unlimited); layer: (1,) i32, the stack
+    entry to read — it rides in SMEM beside the block table, so the page
+    DMAs address the stacked pool directly and no layer is ever sliced
+    out of it.  Returns (B, Hkv, rep, hdv) f32; rows with ``length <= 0``
+    return zeros.
     """
     policy = get_policy(policy_name)
     assert not policy.is_plain(), "paged kernel is for split policies"
     B, Hkv, rep2, hd = q.shape
-    NP, Hkv2, ps, hd2 = k_pages.shape
-    hdv = v_pages.shape[3]
+    _, NP, Hkv2, ps, hd2 = k_pages.shape
+    hdv = v_pages.shape[4]
     assert rep2 == rep and Hkv2 == Hkv and hd2 == hd, (q.shape, k_pages.shape)
-    assert v_pages.shape[:3] == k_pages.shape[:3], (k_pages.shape,
+    assert v_pages.shape[:4] == k_pages.shape[:4], (k_pages.shape,
                                                     v_pages.shape)
     G = pages_per_step
     maxp = block_tables.shape[1]
@@ -198,22 +201,24 @@ def tcec_paged_attention_pallas(q, k_pages, v_pages, block_tables, lengths,
     n_steps = maxp // G
     grid = (B, Hkv, n_steps)
 
-    # one (ps, width) page of one KV head per block: the trailing block
-    # dims equal the pool's, which Mosaic's tiling accepts at any size
+    # one (ps, width) page of one KV head of one layer per block: the
+    # trailing block dims equal the pool's, which Mosaic's tiling accepts
+    # at any size
     def page_spec(j, width):
         return pl.BlockSpec(
-            (1, 1, ps, width),
-            lambda b, h, i, tbl, lens, win, j=j: (tbl[b, i * G + j], h, 0, 0))
+            (1, 1, 1, ps, width),
+            lambda b, h, i, tbl, lens, win, lyr, j=j: (
+                lyr[0], tbl[b, i * G + j], h, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=grid,
         in_specs=[pl.BlockSpec((1, 1, rep, hd),
-                               lambda b, h, i, tbl, lens, win: (b, h, 0, 0))]
+                               lambda b, h, i, *_: (b, h, 0, 0))]
                  + [page_spec(j, hd) for j in range(G)]
                  + [page_spec(j, hdv) for j in range(G)],
         out_specs=pl.BlockSpec((1, 1, rep, hdv),
-                               lambda b, h, i, tbl, lens, win: (b, h, 0, 0)),
+                               lambda b, h, i, *_: (b, h, 0, 0)),
         scratch_shapes=[pltpu.VMEM((rep, 128), jnp.float32),    # running m
                         pltpu.VMEM((rep, 128), jnp.float32)]    # running l
                        + [pltpu.VMEM((rep, hdv), jnp.float32)
@@ -232,7 +237,7 @@ def tcec_paged_attention_pallas(q, k_pages, v_pages, block_tables, lengths,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rep, hdv), jnp.float32),
         interpret=interpret,
         **kwargs,
-    )(block_tables, lengths, window,
+    )(block_tables, lengths, window, layer,
       q, *([k_pages] * G), *([v_pages] * G))
 
 
@@ -240,19 +245,25 @@ def tcec_paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
                          policy: str = "tcec_bf16x6", window=0,
                          softcap: float | None = None,
                          pages_per_step: int | None = None,
+                         layer=None,
                          interpret: bool | None = None) -> jax.Array:
     """Public entry: fused paged decode attention on model-layout operands.
 
     q: (B, H, hd) — one query token per sequence slot; k_pages/v_pages:
-    (NP, Hkv, ps, hd[v]) head-major page pools; block_tables: (B, maxp) i32;
-    lengths: (B,) i32 valid tokens including the current one (the current
-    token's K/V must already be written to its page).  GQA via
+    (NP, Hkv, ps, hd[v]) head-major page pools, or a stack of them (L, NP,
+    Hkv, ps, hd[v]) read at entry ``layer`` (an int or traced scalar; a
+    single pool is a one-entry stack read at 0); block_tables: (B, maxp)
+    i32; lengths: (B,) i32 valid tokens including the current one (the
+    current token's K/V must already be written to its page).  GQA via
     ``H = rep * Hkv``; ``window`` may be a traced scalar (0 = unlimited).
     Returns (B, H, hdv) f32.
     """
+    if k_pages.ndim == 4:
+        assert layer is None, "a single pool has no layer to pick"
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
     B, H, hd = q.shape
-    NP, Hkv, ps, _ = k_pages.shape
-    hdv = v_pages.shape[3]
+    _, NP, Hkv, ps, _ = k_pages.shape
+    hdv = v_pages.shape[4]
     assert H % Hkv == 0, (H, Hkv)
     rep = H // Hkv
     if interpret is None:
@@ -271,6 +282,7 @@ def tcec_paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     qt = q.astype(jnp.float32).reshape(B, Hkv, rep, hd)
     out = tcec_paged_attention_pallas(
         qt, k_pages, v_pages, bt, jnp.asarray(lengths, jnp.int32), win,
+        jnp.asarray(layer, jnp.int32).reshape(1),
         policy_name=policy, rep=rep, pages_per_step=G,
         softcap=(float(softcap) if softcap else None),
         sm_denom=float(np.sqrt(hd)), interpret=interpret)

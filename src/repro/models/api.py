@@ -70,12 +70,15 @@ def get_model(cfg, numerics_config: numerics.NumericsConfig | None = None
                            tokens: mod.decode_step_paged(
                                params, cfg, pools, block_tables, lengths,
                                tokens)) if paged else None,
+        decode_in_place=(lambda pools, batch: mod.decode_in_place(
+            cfg, pools, batch)) if paged else None,
         module=mod,
     )
     if numerics_config is not None:
         for name in ("init", "loss_fn", "forward_logits", "init_cache",
                      "decode_step", "prefill", "prefill_chunk",
-                     "init_paged_cache", "decode_step_paged"):
+                     "init_paged_cache", "decode_step_paged",
+                     "decode_in_place"):
             setattr(handle, name, _pinned(getattr(handle, name),
                                           numerics_config))
     return handle

@@ -363,39 +363,58 @@ def attention_chunk(p, x, cfg, cache, start, window=0):
     return out, {"k": ck, "v": cv}
 
 
-def attention_decode_paged(p, x, cfg, pool, block_tables, lengths, window=0):
+def attention_decode_paged(p, x, cfg, pool, block_tables, lengths, window=0,
+                           layer=None):
     """One-token decode against a paged KV cache (serving engine).
 
     x: (B, 1, d_model) — one token per sequence slot; pool: ``{"k": (NP,
     Hkv, ps, hd), "v": (NP, Hkv, ps, hdv)}`` page arrays shared across
-    slots; block_tables: (B, maxp) i32 page indices per slot; lengths:
-    (B,) i32 tokens already cached per slot (the current token's position).
+    slots — or, with ``layer`` given, every layer's pools stacked, ``(L,
+    NP, Hkv, ps, hd[v])``, of which this layer is entry ``layer``;
+    block_tables: (B, maxp) i32 page indices per slot; lengths: (B,) i32
+    tokens already cached per slot (the current token's position).
 
-    The new token's K/V is scattered into its slot's current page, then
+    The new token's K/V is written into its slot's current page, then
     attention runs through ``dispatch.attention_decode`` (the fused paged
     kernel) when eligible, else gathers the block table into a dense view
     and applies exactly the :func:`attention_decode` math — bitwise the
     same attend as the dense cache path, which is what makes the engine's
-    greedy output token-identical to the legacy dense ``generate()``.
+    greedy output token-identical to the legacy dense ``generate()``.  A
+    stacked pool is written in place by the page-write kernel
+    (``dispatch.kv_write``) and read at ``layer`` by the paged kernel;
+    the caller takes that path only where both are eligible.  Returns the
+    output and the updated pool, stacked as it came.
     """
     from repro.kernels import dispatch
     B = x.shape[0]
     positions = lengths[:, None].astype(jnp.int32)            # (B, 1)
     q, k, v = _project_qkv(p, x, cfg, positions)
-    ps = pool["k"].shape[2]
+    ps = pool["k"].shape[-2]
     maxp = block_tables.shape[1]
     page = block_tables[jnp.arange(B), lengths // ps]         # (B,)
     off = lengths % ps
-    ck = pool["k"].at[page, :, off].set(k[:, 0].astype(pool["k"].dtype))
-    cv = pool["v"].at[page, :, off].set(v[:, 0].astype(pool["v"].dtype))
+    at = () if layer is None else (layer,)
+    written = None
+    if layer is not None:
+        written = dispatch.kv_write(pool["k"], pool["v"], k[:, 0], v[:, 0],
+                                    page, off, layer, policy=cfg.mix_policy)
+    if written is not None:
+        ck, cv = written
+    else:
+        ck = pool["k"].at[at + (page, slice(None), off)].set(
+            k[:, 0].astype(pool["k"].dtype))
+        cv = pool["v"].at[at + (page, slice(None), off)].set(
+            v[:, 0].astype(pool["v"].dtype))
     fused = dispatch.attention_decode(q[:, 0], ck, cv, block_tables,
                                       lengths + 1, policy=cfg.mix_policy,
                                       window=window,
-                                      softcap=cfg.attn_softcap)
+                                      softcap=cfg.attn_softcap, layer=layer)
     if fused is not None:
         o = fused[:, None].astype(jnp.float32)                # (B, 1, H, hdv)
     else:
         def gather(pages):                     # -> (B, maxp * ps, Hkv, d)
+            if layer is not None:
+                pages = pages[layer]
             g = jnp.swapaxes(pages[block_tables], 2, 3)
             return g.reshape((B, maxp * ps) + g.shape[3:])
         kg, vg = gather(ck), gather(cv)

@@ -150,8 +150,9 @@ def stack_decode(stacked, x, cfg, caches, cache_index, windows, *, moe: bool):
 
 
 def block_decode_paged(p, x, cfg, pool, block_tables, lengths, window, *,
-                       moe: bool):
-    """``block_decode`` against one layer's page pool (serving engine)."""
+                       moe: bool, layer=None):
+    """``block_decode`` against one layer's page pool (serving engine), or
+    against every layer's pools stacked, read and written at ``layer``."""
     h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
     if cfg.use_mla:
         a, new_pool = M.mla_decode_paged(p["attn"], h, cfg, pool,
@@ -159,7 +160,7 @@ def block_decode_paged(p, x, cfg, pool, block_tables, lengths, window, *,
     else:
         a, new_pool = L.attention_decode_paged(p["attn"], h, cfg, pool,
                                                block_tables, lengths,
-                                               window=window)
+                                               window=window, layer=layer)
     if cfg.sandwich_norms:
         a = L.rmsnorm(p["post_ln1"], a, cfg.norm_eps)
     x = x + a
@@ -173,8 +174,49 @@ def block_decode_paged(p, x, cfg, pool, block_tables, lengths, window, *,
     return x + m, new_pool
 
 
+def _in_place_query(cfg, batch: int):
+    return jax.ShapeDtypeStruct((batch, cfg.n_heads, cfg.head_dim),
+                                jnp.float32)
+
+
+def decode_in_place(cfg, pools, batch: int) -> bool:
+    """Whether :func:`decode_step_paged` keeps the page pools in place
+    under the active numerics config and mesh: every stack's pools are
+    head-major K/V (not MLA's latent pools) and both the page-write and
+    the paged attention kernel are eligible for them.  Records nothing;
+    the serving engine asks it to count the steps run in place."""
+    from repro.kernels import dispatch
+    return all(dispatch.kv_write_reason(_in_place_query(cfg, batch), pool,
+                                        policy=cfg.mix_policy) == "fused"
+               for pool in pools.values())
+
+
 def stack_decode_paged(stacked, x, cfg, pools, block_tables, lengths,
                        windows, *, moe: bool):
+    """Decode through a stack of layers against its page pools.
+
+    In place (both kernels eligible — :func:`decode_in_place`): the
+    stacked pools ride in the scan carry beside ``x`` and each layer
+    writes its token into them and reads them at its own index, so no
+    layer's pool is sliced out, re-laid-out or written back.  Otherwise
+    the scan takes each layer's pool as ``xs`` and returns it as ``ys``,
+    scattering with XLA."""
+    from repro.kernels import dispatch
+    if dispatch.kv_write_eligible(_in_place_query(cfg, x.shape[0]), pools,
+                                  policy=cfg.mix_policy):
+        def body(carry, xs):
+            y, pool = carry
+            lp, w, layer = xs
+            return block_decode_paged(lp, y, cfg, pool, block_tables,
+                                      lengths, w, moe=moe,
+                                      layer=layer), None
+
+        n = len(windows)
+        (x, pools), _ = jax.lax.scan(
+            body, (x, pools),
+            (stacked, jnp.asarray(windows), jnp.arange(n, dtype=jnp.int32)))
+        return x, pools
+
     def body(carry, xs):
         lp, pool, w = xs
         y, npool = block_decode_paged(lp, carry, cfg, pool, block_tables,

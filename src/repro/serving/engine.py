@@ -222,6 +222,10 @@ class Engine:
                           "temps", "topks", "topps")}
             for _ in range(2)]
         self.n_decode_steps = 0
+        self.n_decode_steps_in_place = 0
+        # numerics config -> whether its decode program keeps the page
+        # pools in place (a trace-time decision, fixed per config)
+        self._in_place: dict = {}
         self.n_prefills = 0
         self.n_prefill_chunks = 0
         _LIVE_ENGINES.add(self)
@@ -718,9 +722,19 @@ class Engine:
                 args[0], self.pools, *args[1:], prev_keys,
                 jnp.asarray(self._poison_mask()))
             self.n_decode_steps += 1
+            self.n_decode_steps_in_place += self._decode_in_place()
             self.pools, self.keys = pools, keys
             return {"running": running, "args": args, "prev_keys": prev_keys,
                     "toks": toks, "finite": finite}
+
+    def _decode_in_place(self) -> bool:
+        """Whether the decode program of the active numerics config runs
+        in place (``models.lm.decode_in_place``), asked once per config."""
+        nc = numerics.active()
+        if nc not in self._in_place:
+            self._in_place[nc] = self.model.decode_in_place(
+                self.pools, self.max_slots)
+        return self._in_place[nc]
 
     def _decode_consume(self, inflight):
         """Block on a dispatched step's results and apply them — the only
@@ -848,7 +862,8 @@ class Engine:
     def stats(self) -> dict:
         """Resilience and throughput counters: engine counters (guard
         trips, fallback re-runs, rejections, overloads, timeouts, length
-        caps, prefill faults, numerics errors), scheduler counters
+        caps, prefill faults, numerics errors, decode steps and those run
+        with the page pools in place), scheduler counters
         (preemptions, parks), and the kernel circuit breaker's global
         totals.  All zero on a healthy fault-free run — the serving bench
         snapshot records them so CI gates on exactly that."""
@@ -858,6 +873,7 @@ class Engine:
                 "prefills": self.n_prefills,
                 "prefill_chunks": self.n_prefill_chunks,
                 "decode_steps": self.n_decode_steps,
+                "decode_steps_in_place": self.n_decode_steps_in_place,
                 "preemptions": self.sched.n_preemptions,
                 "parks": self.sched.n_parks,
                 "breaker": guard.counters()}

@@ -26,6 +26,7 @@ from repro.models import layers as L
 from repro.serving import (Engine, PagePool, PagePoolError, SamplingParams,
                            Scheduler, sampling)
 from repro.serving.kv_cache import inverse_permutation, permute_pages
+from repro.serving.scheduler import RequestState
 
 
 def _rand(shape, seed):
@@ -302,6 +303,52 @@ def test_paged_kernel_ignores_stale_garbage_in_recycled_pages():
     assert bool(jnp.all(jnp.isfinite(out)))
 
 
+@pytest.mark.parametrize("layer", [0, 2])
+def test_paged_kernel_reads_a_stacked_pool_at_its_layer(layer):
+    """A stack of pools read at ``layer`` is bitwise the kernel on that
+    layer's pool alone (the decode step's in-place path)."""
+    q, kp, vp, bt, lengths = _paged_case(seed=17)
+    rng = np.random.default_rng(18)
+    ks = jnp.stack([kp, -kp, jnp.asarray(rng.standard_normal(kp.shape),
+                                         kp.dtype)])
+    vs = jnp.stack([vp, vp * 2, -vp])
+    out = tcec_paged_attention(q, ks, vs, bt, lengths, layer=jnp.int32(layer),
+                               window=7, pages_per_step=2, interpret=True)
+    ref = tcec_paged_attention(q, ks[layer], vs[layer], bt, lengths,
+                               window=7, pages_per_step=2, interpret=True)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("layer", [0, 3])
+def test_kv_page_write_equals_the_xla_scatter(dtype, layer):
+    """The aliased page write is bitwise ``pool.at[layer, page, :, off]
+    .set(new)``: rows 0 and ps-1, the first and last layer, and several
+    inactive slots all writing the scrap page 0 (whose contents are then
+    unspecified, as they are for a scatter with repeated indices)."""
+    from repro.kernels.kv_page_write import kv_page_write
+    L_, NP, Hkv, ps, hd, hdv = 4, 9, 2, 8, 64, 32
+    rng = np.random.default_rng(19)
+    kp = jnp.asarray(rng.standard_normal((L_, NP, Hkv, ps, hd)), dtype)
+    vp = jnp.asarray(rng.standard_normal((L_, NP, Hkv, ps, hdv)), dtype)
+    page = np.asarray([0, 3, 0, 5, 0, 8], np.int32)
+    off = np.asarray([1, 0, 6, ps - 1, 3, 2], np.int32)
+    B = len(page)
+    kn = jnp.asarray(rng.standard_normal((B, Hkv, hd)), jnp.float32)
+    vn = jnp.asarray(rng.standard_normal((B, Hkv, hdv)), jnp.float32)
+    k2, v2 = kv_page_write(kp, vp, kn, vn, page, off, jnp.int32(layer),
+                           interpret=True)
+    act = page != 0
+    rk = kp.at[layer, page[act], :, off[act]].set(kn[act].astype(dtype))
+    rv = vp.at[layer, page[act], :, off[act]].set(vn[act].astype(dtype))
+    keep = np.ones((L_, NP), bool)
+    keep[layer, 0] = False                  # the scrap page
+    for got, ref in ((k2, rk), (v2, rv)):
+        assert got.dtype == dtype
+        np.testing.assert_array_equal(np.asarray(got)[keep],
+                                      np.asarray(ref)[keep])
+
+
 # -------------------------------------------- dispatch + tuning wiring
 
 def test_paged_dispatch_eligibility_and_hatches(monkeypatch):
@@ -430,16 +477,155 @@ def test_paged_candidates_respect_vmem():
 PARITY_ARCHS = ["qwen3-0.6b", "gemma2-9b", "deepseek-v3-671b"]
 
 
-@pytest.mark.parametrize("arch", PARITY_ARCHS)
-def test_engine_greedy_token_identical_to_dense_generate(arch):
+@pytest.mark.parametrize(
+    "arch,kernels",
+    [pytest.param(a, "off", id=a) for a in PARITY_ARCHS]
+    + [pytest.param(a, "forced", id=f"{a}-forced") for a in PARITY_ARCHS])
+def test_engine_greedy_token_identical_to_dense_generate(arch, kernels):
     """The acceptance contract: transformer / GQA+window+softcap / MLA+MoE
-    — greedy engine output == dense-cache reference, token for token."""
+    — greedy engine output == dense-cache reference, token for token.
+    ``forced`` runs the kernels in interpret mode: the decode step then
+    keeps its pools in place (the page-write and paged kernels on the
+    stacked pools) for the K/V families, and MLA takes the per-layer
+    scan."""
+    import contextlib
     from repro.launch.serve import generate, generate_dense
     cfg, model, params = _model_and_params(arch)
     prompts = _prompts(cfg, (2, 9), seed=5)
     dense = np.asarray(generate_dense(cfg, params, prompts, 6))
-    eng = np.asarray(generate(cfg, params, prompts, 6))
+    scope = (numerics.use(force=True, interpret=True) if kernels == "forced"
+             else contextlib.nullcontext())
+    with scope:
+        eng = np.asarray(generate(cfg, params, prompts, 6))
     np.testing.assert_array_equal(dense, eng)
+
+
+# which kernel knobs keep the decode step's pools in place, and which
+# explain rule declines the in-place path otherwise
+IN_PLACE_CASES = [
+    ("qwen3-0.6b", dict(force=True, interpret=True), "fused"),
+    ("gemma2-9b", dict(force=True, interpret=True), "fused"),
+    ("qwen3-0.6b", dict(force=True, interpret=True, paged_attention=False),
+     "hatch-disabled"),
+    ("qwen3-0.6b", dict(force=True, interpret=True, enabled=False),
+     "hatch-disabled"),
+    ("qwen3-0.6b", {}, "off-backend"),
+    ("deepseek-v3-671b", dict(force=True, interpret=True),
+     "shape-unsupported"),
+]
+
+
+@pytest.mark.parametrize("arch,knobs,rule", IN_PLACE_CASES)
+def test_engine_in_place_decode_counts_and_explains(arch, knobs, rule):
+    """``decode_steps_in_place`` counts every decode step where the path
+    engages and none elsewhere; the ``kv_write`` explain entry reads
+    ``fused`` or names the declining rule; greedy tokens match the
+    dense reference either way (MLA, the hatches and an unforced CPU keep
+    the per-layer scan)."""
+    from repro.launch.serve import generate_dense
+    from repro.obs.explain import decisions, reset
+    reset()
+    cfg, model, params = _model_and_params(arch)
+    prompts = _prompts(cfg, (2, 9), seed=5)
+    dense = np.asarray(generate_dense(cfg, params, prompts, 5))
+    eng = Engine(cfg, params, max_slots=2, num_pages=16, page_size=4,
+                 numerics_config=numerics.active().replace(**knobs))
+    out = eng.run([np.asarray(p) for p in prompts],
+                  SamplingParams(max_tokens=5))
+    np.testing.assert_array_equal(dense, np.stack(list(out.values())))
+    stats = eng.stats()
+    assert stats["decode_steps"] > 0
+    in_place = stats["decode_steps"] if rule == "fused" else 0
+    assert stats["decode_steps_in_place"] == in_place
+    rules = {r for k, v in decisions().items() if "/kv_write/" in k
+             for r in v}
+    assert rules == {rule}, rules
+
+
+def test_engine_nonfinite_rerun_leaves_the_in_place_path():
+    """The guard's one-shot re-run traces under the XLA-fallback scope,
+    which declines the in-place path (hatch closed); the dispatched steps
+    still all ran in place, and tokens match the dense reference."""
+    from repro import faults
+    from repro.launch.serve import generate_dense
+    from repro.obs.explain import decisions, reset
+    reset()
+    cfg, model, params = _model_and_params("qwen3-0.6b")
+    prompts = _prompts(cfg, (2, 9), seed=5)
+    dense = np.asarray(generate_dense(cfg, params, prompts, 5))
+    eng = Engine(cfg, params, max_slots=2, num_pages=16, page_size=4,
+                 numerics_config=numerics.active().replace(
+                     force=True, interpret=True))
+    with faults.use(faults.FaultPlan(
+            [faults.FaultSpec("decode.nonfinite", at=(1,), arg=0)])):
+        out = eng.run([np.asarray(p) for p in prompts],
+                      SamplingParams(max_tokens=5))
+    np.testing.assert_array_equal(dense, np.stack(list(out.values())))
+    stats = eng.stats()
+    assert stats["fallback_reruns"] == 1
+    assert stats["decode_steps_in_place"] == stats["decode_steps"]
+    rules = {r for k, v in decisions().items() if "/kv_write/" in k
+             for r in v}
+    assert rules == {"fused", "hatch-disabled"}, rules
+
+
+def test_engine_kv_write_fault_falls_back_to_the_scatter():
+    """An injected page-write failure (fault site ``kernel.kv_write``)
+    is caught by the guard: the layer scatters into the carried stack
+    with XLA instead, and the tokens do not change."""
+    from repro import faults
+    from repro.kernels import guard
+    from repro.launch.serve import generate_dense
+    from repro.obs.explain import decisions, reset
+    reset()
+    cfg, model, params = _model_and_params("qwen3-0.6b")
+    prompts = _prompts(cfg, (2, 9), seed=5)
+    dense = np.asarray(generate_dense(cfg, params, prompts, 5))
+    eng = Engine(cfg, params, max_slots=2, num_pages=16, page_size=4,
+                 numerics_config=numerics.active().replace(
+                     force=True, interpret=True))
+    plan = faults.plan_from_spec("kernel.kv_write@0")
+    try:
+        with faults.use(plan):
+            out = eng.run([np.asarray(p) for p in prompts],
+                          SamplingParams(max_tokens=5))
+    finally:
+        guard.reset()
+    assert plan.log == [("kernel.kv_write", 0)]
+    np.testing.assert_array_equal(dense, np.stack(list(out.values())))
+    rules = {r for k, v in decisions().items() if "/kv_write/" in k
+             for r in v}
+    assert rules == {"kernel-failure"}, rules
+
+
+def test_engine_never_shares_an_active_slots_current_page():
+    """The in-place write gives each slot's current page to one grid step
+    alone: no two running slots write the same page, none writes the
+    scrap page 0, and no current page is shared with the prefix cache —
+    across slot recycling, shared prefixes and chunked prefill."""
+    cfg, model, params = _model_and_params("qwen3-0.6b")
+    rng = np.random.default_rng(10)
+    shared = list(rng.integers(0, cfg.vocab_size, 12))
+    prompts = ([shared + list(rng.integers(0, cfg.vocab_size, n))
+                for n in (0, 1, 3, 4, 6)]
+               + [list(rng.integers(0, cfg.vocab_size, n)) for n in (5, 9)])
+    eng = Engine(cfg, params, max_slots=3, num_pages=48, page_size=4,
+                 numerics_config=numerics.active().replace(
+                     prefix_cache=True, chunked_prefill=8))
+    ps, seen = eng.pool.page_size, []
+    dispatch_ = eng._decode_dispatch
+
+    def checked():
+        pages = [int(eng.block_tables[r.slot, eng.lengths[r.slot] // ps])
+                 for r in eng.sched.running.values()
+                 if r.state is RequestState.RUNNING]
+        assert 0 not in pages and len(set(pages)) == len(pages), pages
+        assert all(eng.pool.refcount(p) == 1 for p in pages), pages
+        seen.append(len(pages))
+        return dispatch_()
+    eng._decode_dispatch = checked
+    eng.run(prompts, SamplingParams(max_tokens=6))
+    assert max(seen) == 3 and eng.stats()["prefix_hits"] > 0
 
 
 def test_engine_mixed_lengths_match_per_request_dense():

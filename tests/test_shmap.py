@@ -454,3 +454,71 @@ def test_sharded_parity_battery_subprocess():
                        capture_output=True, text=True, cwd=root,
                        timeout=900)
     assert "OK" in r.stdout, (r.stdout[-2000:], r.stderr[-4000:])
+
+
+ENGINE_MESH_SUBPROC = textwrap.dedent("""
+    import contextlib, os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import sys; sys.path.insert(0, "src")
+    import jax, numpy as np
+    from repro import numerics
+    from repro.configs import get_smoke_config
+    from repro.kernels import shmap
+    from repro.launch.mesh import make_mesh
+    from repro.models import get_model
+    from repro.obs.explain import decisions, reset
+    from repro.parallel import ctx
+    from repro.serving import Engine, SamplingParams
+
+    cfg = get_smoke_config("qwen3-0.6b")            # 2 KV heads
+    params = get_model(cfg).init(jax.random.PRNGKey(0))
+    rng = np.random.default_rng(2)
+    prompts = [list(rng.integers(0, cfg.vocab_size, n)) for n in (5, 9)]
+    sp = SamplingParams(temperature=0.0, max_tokens=5)
+    forced = numerics.active().replace(force=True, interpret=True)
+
+    def serve(nc, mesh=None):
+        with (ctx.use_mesh(mesh) if mesh is not None
+              else contextlib.nullcontext()):
+            eng = Engine(cfg, params, max_slots=2, numerics_config=nc)
+        out = eng.run(prompts, sp)
+        return [list(v) for v in out.values()], eng.stats()
+
+    def kv_write_rules():
+        return {r for k, v in decisions().items() if "/kv_write/" in k
+                for r in v}
+
+    one, st = serve(forced)
+    assert st["decode_steps_in_place"] == st["decode_steps"] > 0
+    # KV heads on "model", alone and beside a data axis: the stacked-pool
+    # write and the paged kernel run per shard, token-identical
+    for shape in ((1, 2), (2, 2)):
+        reset()
+        n0 = shmap.counters()["kv_write"]
+        got, st = serve(forced, make_mesh(shape, ("data", "model")))
+        assert got == one, (shape, got, one)
+        assert st["decode_steps_in_place"] == st["decode_steps"] > 0
+        assert shmap.counters()["kv_write"] > n0, shape
+        assert kv_write_rules() == {"fused"}, kv_write_rules()
+    # a declined plan (the shard_map knob off) keeps the per-layer scan:
+    # token-identical to one device on the same XLA attention path
+    plain, _ = serve(numerics.active())
+    reset()
+    got, st = serve(forced.replace(shard_map=False),
+                    make_mesh((1, 2), ("data", "model")))
+    assert got == plain, (got, plain)
+    assert st["decode_steps_in_place"] == 0 and st["decode_steps"] > 0
+    assert kv_write_rules() == {"mesh-declined"}, kv_write_rules()
+    print("OK")
+""")
+
+
+def test_engine_in_place_decode_under_mesh_subprocess():
+    """The decode step's in-place path under ``shard_map`` on 4 virtual
+    CPU devices matches one device token for token; a declined plan
+    falls back to the per-layer scan."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    r = subprocess.run([sys.executable, "-c", ENGINE_MESH_SUBPROC],
+                       capture_output=True, text=True, cwd=root,
+                       timeout=600)
+    assert "OK" in r.stdout, (r.stdout[-2000:], r.stderr[-4000:])

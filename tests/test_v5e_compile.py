@@ -106,3 +106,48 @@ def test_paged_decode_compiles(one_chip):
              s((B, 16, 128)), s((NP, 8, ps, 128), jnp.bfloat16),
              s((NP, 8, ps, 128), jnp.bfloat16), s((B, maxp), jnp.int32),
              s((B,), jnp.int32))
+
+
+def test_decode_step_keeps_the_page_pool_in_place(one_chip):
+    """The serving engine's decode step at smoke widths but the published
+    head_dim (128 lanes: a narrower head pads in the TPU's tiled layout,
+    which no deployment has), with a page pool larger than VMEM (which
+    small arrays may be copied into) and large enough to dominate the
+    step's memory: the stacked pool flows through
+    the layer scan in place.  A second copy of the pool among the
+    temporaries, or any copy, slice or update of the whole stack, is
+    the regression (XLA re-lays-out or re-stacks the pool when the scan
+    takes it as ``xs``/``ys`` or scatters into the carried stack)."""
+    import dataclasses
+    import functools
+    import re
+    from repro import numerics
+    from repro.configs import get_smoke_config
+    from repro.models import get_model
+    from repro.serving import engine as eng_mod
+    cfg = dataclasses.replace(get_smoke_config("qwen3-0.6b"), head_dim=128)
+    model = get_model(cfg)
+    s = _spec(one_chip)
+    on = lambda tree: jax.tree.map(lambda a: s(a.shape, a.dtype), tree)
+    B, maxp, NP, ps = 4, 16, 5001, 16      # 2 x 164 MB of f32 K/V pages
+    params = on(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    pools = on(jax.eval_shape(functools.partial(
+        model.init_paged_cache, NP, ps, dtype=jnp.float32)))
+    i32, f32 = jnp.int32, jnp.float32
+    with numerics.use(force=True, interpret=False, guard=False):
+        compiled = jax.jit(functools.partial(
+            eng_mod._decode_and_sample, model=model, cfg=cfg),
+            donate_argnums=(1,)).lower(
+                params, pools, s((B, maxp), i32), s((B,), i32), s((B,), i32),
+                s((B,), f32), s((B,), i32), s((B,), f32),
+                s((B, 2), jnp.uint32), s((B,), jnp.bool_)).compile()
+    pool = pools["dense_blocks"]["k"]
+    pool_bytes = 2 * pool.size * 4                       # K and V
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
+    shape = "f32[" + ",".join(map(str, pool.shape)) + "]"
+    moves = [ln for ln in compiled.as_text().splitlines()
+             if re.search(re.escape(shape) + r"\S* (copy|dynamic-slice|"
+                      r"dynamic-update-slice|copy-start)\(", ln)
+             or re.search(r"= " + re.escape(shape) + r"\S* fusion\(", ln)]
+    assert not moves, moves[:3]
+    assert "kv_page_write_pallas" in compiled.as_text()
