@@ -29,7 +29,7 @@ for _p in (str(_ROOT), str(_ROOT / "src")):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
-from chipbench import check, faults, run, spec, weights  # noqa: E402
+from chipbench import check, faults, run, spec  # noqa: E402
 
 CONTROL_POLICY = "tcec_bf16x3"
 CONTROLS = {"high": {"precision": "high", "kv_dtype": "float32"},
@@ -43,7 +43,9 @@ def main(argv=None) -> int:
     ap.add_argument("--control-seeds", default="")
     ap.add_argument("--fault-seeds", default="",
                     help="also run these seeds under planted faults")
-    ap.add_argument("--faults", default=",".join(faults.FAULTS))
+    ap.add_argument("--faults", default="",
+                    help="comma-separated; default: every fault the cell "
+                    "can have")
     ap.add_argument("--seconds", type=float, default=15.0)
     args = ap.parse_args(argv)
     cell = spec.cell(args.workload)
@@ -56,10 +58,13 @@ def main(argv=None) -> int:
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", str(run.CACHE_DIR))
     ctl_seeds = {int(s) for s in args.control_seeds.split(",") if s}
+    chips = int(cell["workload"]["chips"])
+    devices = devices[:chips]
+    planted = faults.for_chips(chips)
+    mesh = run.make_mesh(cell["config"], devices)
     for seed in [int(s) for s in args.seeds.split(",")]:
         kept = []
-        res = run.run_cell(cell, seed, args.seconds, False,
-                           devices[: int(cell["workload"]["chips"])],
+        res = run.run_cell(cell, seed, args.seconds, False, devices,
                            keep_sample=kept)
         row = {"seed": seed, "program": {k: v["value"] for k, v in
                                          res["check"].items()},
@@ -68,8 +73,7 @@ def main(argv=None) -> int:
         if seed in ctl_seeds:
             low = copy.deepcopy(cell)
             low["config"]["numerics"]["policy"] = CONTROL_POLICY
-            ctl = run.run_cell(low, seed, args.seconds, False,
-                               devices[: int(cell["workload"]["chips"])],
+            ctl = run.run_cell(low, seed, args.seconds, False, devices,
                                keep_sample=[])
             row[CONTROL_POLICY] = {
                 **{k: v["value"] for k, v in ctl["check"].items()},
@@ -80,7 +84,7 @@ def main(argv=None) -> int:
             config = cell["config"]
             ref = spec.load_module(spec.reference_path(config["reference"]),
                                    "chipbench_reference")
-            w = weights.hf_view(weights.make_params(cfg, seed), cfg)
+            w = run.reference_weights(cfg, seed, mesh)
             for name, ctl in CONTROLS.items():
                 got = check.compare(ref, w, config["hf_config"], sample,
                                     control=ctl)
@@ -90,10 +94,9 @@ def main(argv=None) -> int:
         del kept
         print(json.dumps(row), flush=True)
     for seed in [int(s) for s in args.fault_seeds.split(",") if s]:
-        for name in args.faults.split(","):
-            with faults.FAULTS[name]():
-                res = run.run_cell(cell, seed, args.seconds, False,
-                                   devices[: int(cell["workload"]["chips"])])
+        for name in args.faults.split(",") if args.faults else planted:
+            with planted[name]():
+                res = run.run_cell(cell, seed, args.seconds, False, devices)
             print(json.dumps({"seed": seed, "fault": name,
                               "correct": res["correct"],
                               "numbers": {k: v["value"] for k, v in

@@ -45,16 +45,17 @@ class Ctx:
         return sum(work.step_flops(self.dims, self.vocab, s) for s in steps)
 
     def kernel_min_s(self, kernel: str) -> tuple[float, int]:
-        """Least time the chip could take for ``kernel``'s work in the
-        span, summed call by call, and the number of calls."""
-        total, calls = 0.0, 0
+        """Least time the chips could take for ``kernel``'s work in the
+        span, summed event by event over every chip
+        (:func:`work.per_chip`), and the number of events."""
+        total, events = 0.0, 0
         for s in self.steps:
-            for c in work.step_kernel_calls(self.dims, s, self.chunk)[kernel]:
-                flops, nbytes = c
+            calls = work.step_kernel_calls(self.dims, s, self.chunk)[kernel]
+            for flops, nbytes in work.per_chip(calls, self.chips):
                 total += max(flops / self.peak["bf16_flops_per_s"],
                              nbytes / self.peak["hbm_bytes_per_s"])
-                calls += 1
-        return total, calls
+                events += 1
+        return total, events
 
     def kernel_events(self, kernel: str) -> list:
         pat = KERNEL_OPS[kernel]
@@ -63,19 +64,20 @@ class Ctx:
                 if pat.search(e.name) and e.start >= lo and e.end <= hi]
 
     def roofline(self, kernel: str):
-        """Share (%) of the roofline: least time over device time.  None
-        where the trace holds another number of the kernel's calls than
-        :func:`work.step_kernel_calls` counts: the program then sends it
-        other work than that model of its dispatch knows, and the share
-        would count work the kernel did not do, or miss work it did."""
+        """Share (%) of the roofline: least time over device time, both
+        summed over the cell's chips.  None where the trace holds another
+        number of the kernel's events than :func:`work.step_kernel_calls`
+        counts calls, times the chips: the program then sends it other
+        work than that model of its dispatch knows, and the share would
+        count work the kernel did not do, or miss work it did."""
         evs = self.kernel_events(kernel)
         if not evs:
             return None
-        min_s, calls = self.kernel_min_s(kernel)
-        if calls != len(evs):
-            print(f"chipbench: {kernel}: {len(evs)} calls in the trace, "
-                  f"{calls} in the dispatch model; no roofline",
-                  file=sys.stderr, flush=True)
+        min_s, events = self.kernel_min_s(kernel)
+        if events != len(evs):
+            print(f"chipbench: {kernel}: {len(evs)} events in the trace, "
+                  f"{events} in the dispatch model over {self.chips} "
+                  "chip(s); no roofline", file=sys.stderr, flush=True)
             return None
         dev_s = sum(e.dur for e in evs) * 1e-9
         return 100.0 * min_s / dev_s

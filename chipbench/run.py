@@ -131,7 +131,20 @@ def validate(mix: dict, sizes: dict):
                 "above it, or prefill shapes would follow the batch")
 
 
-def build(cell: dict, seed: int):
+def make_mesh(config: dict, devices):
+    """The ``("data", "model")`` mesh of shape ``(1, N)`` over the first
+    ``N`` of ``devices``, where the configuration shares every layer over
+    ``N`` chips; None for one chip."""
+    n = spec.mesh_size(config)
+    if n == 1:
+        return None
+    import numpy as np
+    from jax.sharding import AxisType, Mesh
+    return Mesh(np.asarray(devices[:n]).reshape(1, n), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+
+
+def build(cell: dict, seed: int, mesh=None):
     import jax.numpy as jnp
     from repro.serving import Engine
     from chipbench import weights
@@ -139,14 +152,15 @@ def build(cell: dict, seed: int):
     cfg = spec.model_config(config)
     sizes = engine_sizes(config, mix)
     validate(mix, sizes)
-    params = weights.make_params(cfg, seed)
+    params = weights.make_params(cfg, seed,
+                                 shardings=weights.shardings(cfg, mesh))
     engine = Engine(cfg, params, max_slots=sizes["max_slots"],
                     num_pages=sizes["num_pages"],
                     page_size=sizes["page_size"],
                     max_pages_per_slot=sizes["max_pages_per_slot"],
                     numerics_config=numerics_for(config, mix),
                     cache_dtype=jnp.dtype(sizes["cache_dtype"]),
-                    max_preemptions=None)
+                    max_preemptions=None, mesh=mesh)
     return cfg, engine, sizes
 
 
@@ -284,28 +298,42 @@ def e2e_metrics(rec: Recorder, ws: float, we: float, t_end: float) -> dict:
                  "itl_p50_ms": 1e3 * pct(gaps, 50) if gaps else None}
 
 
-def device_info(devs) -> dict:
-    peak = 0
+def peak_bytes(devs) -> list:
+    """Each device's peak bytes in use so far in the process (0 where the
+    backend does not report it)."""
+    out = []
     for d in devs:
         try:
-            peak = max(peak, int(d.memory_stats()["peak_bytes_in_use"]))
+            out.append(int(d.memory_stats()["peak_bytes_in_use"]))
         except Exception:  # noqa: BLE001 — a backend without the stat
-            pass
+            out.append(0)
+    return out
+
+
+def device_info(devs, peaks: list) -> dict:
     return {"platform": devs[0].platform, "kind": devs[0].device_kind,
-            "count": len(devs), "memory_peak_bytes": peak}
+            "count": len(devs), "memory_peak_bytes": max(peaks)}
+
+
+def reference_weights(cfg, seed: int, mesh=None) -> dict:
+    """The run's weights drawn again from the seed, laid out over the mesh
+    as the program's were, under the published names the reference
+    reads."""
+    from chipbench import weights
+    params = weights.make_params(cfg, seed,
+                                 shardings=weights.shardings(cfg, mesh))
+    return weights.hf_view(params, cfg)
 
 
 def check_numbers(cell: dict, cfg, seed: int, sample: list,
-                  errored: int) -> tuple[dict, dict]:
+                  errored: int, mesh=None) -> tuple[dict, dict]:
     """The compared numbers for the sample, each beside its limit, and
     what :func:`check.compare` read."""
-    from chipbench import check, weights
+    from chipbench import check
     config = cell["config"]
     ref = spec.load_module(spec.reference_path(config["reference"]),
                            "chipbench_reference")
-    params = weights.make_params(cfg, seed)
-    w = weights.hf_view(params, cfg)
-    del params
+    w = reference_weights(cfg, seed, mesh)
     got = check.compare(ref, w, config["hf_config"], sample)
     limits = cell["limits"]["limits"]
     nums = {"token_gap": got["token_gap"], "kv_rel_err": got["kv_rel_err"],
@@ -330,7 +358,8 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     counter = CompileCounter()
     from chipbench import traffic
     mix = cell["traffic"]
-    cfg, engine, sizes = build(cell, seed)
+    mesh = make_mesh(cell["config"], devices)
+    cfg, engine, sizes = build(cell, seed, mesh)
     warm(engine, mix, cfg.vocab_size)
     programs_after_warm = counter.snapshot()
 
@@ -384,7 +413,8 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
         jax.profiler.stop_trace()
     e2e, extra = e2e_metrics(rec, ws, we, t_end)
     e2e["setup_s"] = setup_s
-    dev = device_info(devices)
+    serving_peaks = peak_bytes(devices)
+    dev = device_info(devices, serving_peaks)
     stats = engine.stats()
 
     # ---- what the timed path produced, for the comparison
@@ -422,7 +452,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     del engine, rec.engine
     gc.collect()
     t_chk = time.perf_counter()
-    numbers, got = check_numbers(cell, cfg, seed, sample, errored)
+    numbers, got = check_numbers(cell, cfg, seed, sample, errored, mesh)
     if keep_sample is not None:
         keep_sample += [sample, cfg]
     correct = all(v["value"] is not None and v["value"] <= v["limit"]
@@ -436,7 +466,12 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
         "setup_s": setup_s, "window_s": we - ws,
         "generator_late_p95_ms": 1e3 * pct(lateness, 95) if lateness else 0,
         "engine": {k: v for k, v in stats.items() if k != "breaker"},
-        "check_s": check_s, "compared": got, **extra,
+        "check_s": check_s, "compared": got,
+        # the process's peak per chip: after the window, and after the
+        # check (higher only where the check set it)
+        "peak_bytes_per_chip": {"serving": serving_peaks,
+                                "after_check": peak_bytes(devices)},
+        **extra,
     }
     log("run: " + json.dumps(info, default=str))
     if trace:

@@ -80,6 +80,10 @@ def cell(workload: str, bench: dict | None = None) -> dict:
     configs = {c["name"]: c for c in bench["configs"]}
     centry = configs[w["config"]]
     config = _read_json(ROOT / centry["file"])
+    if mesh_size(config) != int(w["chips"]):
+        raise SpecError(f"{workload}: the configuration's mesh shares each "
+                        f"layer over {mesh_size(config)} chips, but the "
+                        f"cell asks for {w['chips']}")
     traffic = _read_json(traffic_path(w["traffic"]))
     limits = _read_json(limits_path(workload))
     e2e = [m for m in bench["end_to_end"]
@@ -89,6 +93,12 @@ def cell(workload: str, bench: dict | None = None) -> dict:
     return {"workload": w, "config_entry": centry, "config": config,
             "traffic": traffic, "limits": limits, "end_to_end": e2e,
             "per_layer": per_layer}
+
+
+def mesh_size(config: dict) -> int:
+    """Chips that share every layer: the configuration's ``mesh`` gives
+    the size of its tensor-parallel ``model`` axis (1 when absent)."""
+    return int(config.get("mesh", {}).get("model", 1))
 
 
 def model_config(config: dict):

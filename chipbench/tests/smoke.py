@@ -3,7 +3,9 @@
 It takes the published configuration file of a cell and shrinks every
 size, so the harness, the engine and the reference all run their real
 code paths in seconds.  Pallas kernels run in interpret mode where
-``interpret`` is set."""
+``interpret`` is set.  With ``chips`` above 1 the cell shares every layer
+over a tensor-parallel mesh of that many devices, with as many KV heads
+as chips so that the heads divide."""
 from __future__ import annotations
 
 import copy
@@ -16,9 +18,14 @@ SMOKE_SIZES = {"num_hidden_layers": 2, "hidden_size": 64,
 
 
 def smoke_cell(workload: str = "qwen3-0.6b.code", *,
-               interpret: bool = False) -> dict:
+               interpret: bool = False, chips: int = 1) -> dict:
     cell = copy.deepcopy(spec.cell(workload))
     cell["config"]["hf_config"].update(SMOKE_SIZES)
+    if chips > 1:
+        cell["config"]["mesh"] = {"model": chips}
+        cell["workload"]["chips"] = chips
+        cell["config"]["hf_config"].update(num_attention_heads=2 * chips,
+                                           num_key_value_heads=chips)
     if interpret:
         cell["config"]["numerics"].update(force=True, interpret=True,
                                           min_dim=64)

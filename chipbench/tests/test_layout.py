@@ -1,6 +1,7 @@
 """Every piece BENCHMARK.json names is found by name, and the file keeps
 to the benchmark's format."""
 import json
+import pathlib
 import re
 
 import pytest
@@ -65,11 +66,36 @@ def test_cell_pieces_found(workload):
         assert m["moves"] in names
 
 
+#: readers whose metric no cell reports yet: ``collective_share`` waits
+#: for the first cell whose layers are shared over several chips (on one
+#: chip it would read 0)
+AWAITING_A_CELL = {"collective_share"}
+
+
 def test_every_metric_file_is_named():
     read_by = {spec.metric_path(m["name"]).name[:-3]
                for m in BENCH["per_layer"]}
     files = {p.name[:-3] for p in (spec.PKG / "metrics").glob("*.py")}
-    assert files == read_by
+    assert read_by <= files
+    assert files - read_by <= AWAITING_A_CELL
+
+
+def test_a_mesh_must_match_the_chips(tmp_path):
+    """A configuration that shares its layers over four chips cannot run
+    in a cell that asks for one, nor one chip's configuration in a cell
+    of four."""
+    for chips, mesh in ((1, 4), (4, 1)):
+        bench = json.loads(json.dumps(BENCH))
+        w = bench["workloads"][0]
+        w["chips"] = chips
+        c = next(c for c in bench["configs"] if c["name"] == w["config"])
+        config = json.loads((spec.ROOT / c["file"]).read_text())
+        config["mesh"] = {"model": mesh}
+        c["file"] = str(tmp_path / "config.json")
+        pathlib.Path(c["file"]).write_text(json.dumps(config))
+        with pytest.raises(spec.SpecError, match="mesh"):
+            spec.cell(w["name"], bench)
+    assert spec.mesh_size(spec.cell(CELLS[0])["config"]) == 1
 
 
 def test_run_without_a_chip_prints_no_result():

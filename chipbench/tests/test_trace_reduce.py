@@ -58,3 +58,36 @@ def test_op_name_and_leaves():
     evs = [E("while.8", 0, 100), E("a", 10, 20), E("b", 30, 40),
            E("c", 120, 130)]
     assert [e.name for e in tr.leaves(evs)] == ["a", "b", "c"]
+
+
+def _collective_share(ops, win):
+    import types
+    from chipbench import spec
+    mod = spec.load_module(spec.metric_path("collective_share"),
+                           "chipbench_metric_collective_share")
+    return mod.read(types.SimpleNamespace(
+        win=win, trace=tr.Trace(ops=ops, annotations=[])))
+
+
+def test_collective_share_by_hand():
+    """Chip 0: an all-reduce's halves overlap (0-10 and 5-20 ms: 20 ms
+    together) beside a fusion that is no collective; chip 1: an
+    all-gather's halves 0-5 and 30-35 ms, and an async collective-permute
+    fusion 60-70 ms.  Over a 100 ms span: 20% and 20%."""
+    ms = 1_000_000
+    ops = {0: [E("all-reduce-start.1", 0, 10 * ms),
+               E("all-reduce-done.1", 5 * ms, 20 * ms),
+               E("fusion.7", 20 * ms, 40 * ms)],
+           1: [E("all-gather-start.3", 0, 5 * ms),
+               E("copy.2", 5 * ms, 30 * ms),
+               E("all-gather-done.3", 30 * ms, 35 * ms),
+               E("collective-permute-start-fusion", 60 * ms, 70 * ms)]}
+    assert abs(_collective_share(ops, (0, 100 * ms)) - 20.0) < 1e-9
+    # clipped to a 10-50 ms span: chip 0 10 of 40 ms, chip 1 5 of 40
+    assert abs(_collective_share(ops, (10 * ms, 50 * ms))
+               - 0.5 * (25.0 + 12.5)) < 1e-9
+
+
+def test_collective_share_without_collectives_is_zero():
+    ops = {0: [E("fusion.1", 0, 50), E("tcec_matmul_pallas.2", 50, 90)]}
+    assert _collective_share(ops, (0, 100)) == 0.0

@@ -56,10 +56,10 @@ def test_step_kernel_calls_count():
     assert len(calls["paged"]) == 3     # one decode step, one call a layer
 
 
-def _ctx(n_events):
+def _ctx(n_events, chips=1):
     """A traced span holding one decode step over two live slots (one
     paged call per layer, by the dispatch model) and ``n_events`` paged
-    kernel events of 1 ms each."""
+    kernel events of 1 ms each on each of ``chips`` chips."""
     from chipbench import peaks, trace_reduce
     from chipbench.layer_metrics import Ctx
     dims = work.Dims(layers=2, d_model=64, heads=4, kv_heads=2,
@@ -69,8 +69,10 @@ def _ctx(n_events):
            for i in range(n_events)]
     step = {"chunk_spans": [], "tokens": [(100, 1), (200, 5)]}
     return Ctx(steps=[step], dims=dims, vocab=256, chunk=64,
-               peak=peaks.for_kind("TPU v5 lite"), chips=1,
-               trace=trace_reduce.Trace(ops={0: evs}, annotations=[]),
+               peak=peaks.for_kind("TPU v5 lite"), chips=chips,
+               trace=trace_reduce.Trace(ops={c: list(evs)
+                                             for c in range(chips)},
+                                        annotations=[]),
                win=(0, 10 ** 9), stats0={}, stats1={})
 
 
@@ -85,3 +87,25 @@ def test_roofline_needs_the_trace_to_hold_the_modelled_calls():
         < 1e-9
     assert _ctx(1).roofline("paged") is None
     assert _ctx(3).roofline("paged") is None
+
+
+def test_per_chip_splits_each_call_evenly():
+    calls = [(8.0, 40.0), (4.0, 12.0)]
+    assert work.per_chip(calls, 1) == calls
+    assert work.per_chip(calls, 4) == [(2.0, 10.0)] * 4 + [(1.0, 3.0)] * 4
+
+
+def test_roofline_over_four_chips_expects_an_event_per_chip():
+    """Four chips share each layer: each of the two paged calls is one
+    event on every chip, with a quarter of the call's work.  Two events a
+    chip give the eight events' least times over their 8 ms; the one-chip
+    count of events on one chip gives no share."""
+    flops, nbytes = work.paged([101, 205], heads=4, kv_heads=2,
+                               head_dim=16)
+    least = max(flops / 4 / 197e12, nbytes / 4 / 819e9)
+    assert abs(_ctx(2, chips=4).roofline("paged")
+               - 100.0 * 8 * least / 8e-3) < 1e-9
+    one = _ctx(2, chips=4)
+    one.trace.ops = {0: one.trace.ops[0]}
+    assert one.roofline("paged") is None
+    assert _ctx(1, chips=4).roofline("paged") is None

@@ -38,10 +38,26 @@ def _scale(path: str, shape, cfg) -> float:
     return 1.0 / np.sqrt(fan_in)
 
 
-def make_params(cfg, seed: int, shardings=None):
-    """The program-layout weight tree for ``cfg``, drawn from ``seed``."""
+def _shapes(cfg):
     from repro.models import get_model
-    shapes = jax.eval_shape(get_model(cfg).init, jax.random.PRNGKey(0))
+    return jax.eval_shape(get_model(cfg).init, jax.random.PRNGKey(0))
+
+
+def shardings(cfg, mesh):
+    """The layout the program serves its weights in over ``mesh``
+    (``repro.parallel.sharding.param_specs``: heads, the MLP hidden width
+    and the vocabulary on the ``model`` axis); None without a mesh."""
+    if mesh is None:
+        return None
+    from repro.parallel.sharding import param_specs, to_shardings
+    return to_shardings(param_specs(_shapes(cfg), mesh, cfg), mesh)
+
+
+def make_params(cfg, seed: int, shardings=None):
+    """The program-layout weight tree for ``cfg``, drawn from ``seed``,
+    each leaf made where ``shardings`` puts it: the same numbers with or
+    without them."""
+    shapes = _shapes(cfg)
     flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
     paths = ["/".join(str(getattr(k, "key", k)) for k in p) for p, _ in flat]
     leaves = [s for _, s in flat]

@@ -112,6 +112,16 @@ def paged(contexts, heads: int, kv_heads: int,
     return flops, float(b)
 
 
+def per_chip(calls, chips: int) -> list:
+    """The kernel events that ``calls`` make where ``chips`` chips share
+    every layer: each call is one event on each chip, with a ``1/chips``
+    share of the call's operations and bytes.  An even split is the
+    model's own work over the chips, whichever of a product's dimensions
+    the program shards."""
+    return [(flops / chips, nbytes / chips)
+            for flops, nbytes in calls for _ in range(chips)]
+
+
 def step_kernel_calls(dims: Dims, step: dict, chunk: int) -> dict:
     """Per kernel, the ``(flops, bytes)`` of every call the fused kernels
     make in one engine step.  Each prefill chunk runs its seven
